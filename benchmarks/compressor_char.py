@@ -28,10 +28,9 @@ from repro.core import cost_model as cm
 from repro.core.compressor import ErrorBoundedLorenzo
 
 SIZES_MB = [0.25, 0.5, 1, 2, 5, 10, 20, 40]
-# CPU-interpret caveat: the fused pack kernel's resident output window is
-# round-tripped per grid step by the interpreter (it stays in VMEM on TPU),
-# so fused COMPRESS wall-clock on CPU is pessimistic; the fused receive
-# side (no big resident output) shows the real op-count win (~2x).
+# CPU-interpret caveat: the interpreter runs each grid step's wire-stream
+# DMA as an update of the whole HBM buffer, so fused wall-clock on CPU
+# grows with the payload and says nothing about the chip.
 FUSED_SIZES_MB = [1, 4]
 BASELINE_PATH = pathlib.Path(__file__).parent / "BENCH_compress.json"
 

@@ -123,8 +123,8 @@ class GZConfig:
     behaviour); "fallback" re-executes the collective through the
     uncompressed lossless schedule inside the trace (``lax.cond``) when
     any stream overflowed or any input held NaN/Inf, so the result is
-    exact whenever compression failed; "raise" raises from a debug
-    callback on the host (debugging aid — aborts the computation).
+    exact whenever compression failed; "raise" raises from an
+    ``io_callback`` on the host (debugging aid — aborts the computation).
 
     ``verify_streams`` ships a per-hop XOR checksum alongside every
     compressed ppermute and treats a mismatch exactly like overflow
@@ -187,18 +187,13 @@ class GZConfig:
 
 def _axis_size(axis_name) -> int:
     # Composite (tuple/list) axis names — collectives over a flattened 2D
-    # mesh ("node", "local") — multiply out; jax.core.axis_frame only
-    # resolves single names.
+    # mesh ("node", "local") — multiply out.
     if isinstance(axis_name, (tuple, list)):
         n = 1
         for ax in axis_name:
             n *= _axis_size(ax)
         return n
-    if hasattr(lax, "axis_size"):  # JAX >= 0.6
-        return lax.axis_size(axis_name)
-    from jax import core
-
-    return int(core.axis_frame(axis_name))
+    return lax.axis_size(axis_name)
 
 
 def _ppermute(tree, axis_name, perm):

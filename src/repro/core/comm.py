@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental import io_callback
 
 from repro.core import codecs, cost_model, error_budget, faults, schedule
 from repro.core.compressed import capacity_words_for
@@ -1313,6 +1314,9 @@ def _emit_health(op, axis_name, overflow, nonfinite, fell_back) -> None:
 
 
 def _raise_degraded(what, ovf, nonfinite):
+    """Host side of ``on_overflow="raise"``.  Runs as an ``io_callback``:
+    an exception raised in a ``jax.debug.callback`` is only logged, while
+    one raised here fails the computation (``JaxRuntimeError``)."""
     if bool(ovf) or bool(nonfinite):
         raise RuntimeError(
             f"gZ collective degraded ({what}): overflow={bool(ovf)} "
@@ -1501,9 +1505,9 @@ class GZCommunicator:
             )
             fell_back = degraded
         elif plan.on_overflow == "raise":
-            jax.debug.callback(
+            io_callback(
                 partial(_raise_degraded, f"{op} over {self.axis_name!r}"),
-                overflow, nonfinite,
+                None, overflow, nonfinite,
             )
         _emit_health(op, self.axis_name, overflow, nonfinite, fell_back)
         return CollectiveResult(
@@ -1729,9 +1733,9 @@ class GZHierCommunicator:
             )
             fell_back = degraded
         elif hplan.on_overflow == "raise":
-            jax.debug.callback(
+            io_callback(
                 partial(_raise_degraded, f"allreduce over {axes!r}"),
-                overflow, nonfinite,
+                None, overflow, nonfinite,
             )
         _emit_health("allreduce", axes, overflow, nonfinite, fell_back)
         return CollectiveResult(

@@ -1,10 +1,9 @@
 """shard_map wrapper used framework-wide.
 
-Replication checking is disabled (``check_vma``/``check_rep`` depending on
-the JAX version) because Pallas calls inside shard_map bodies cannot
-declare varying-mesh-axes on their ShapeDtypeStruct outputs; the
-collectives and model layers are written rank-centric and manage
-replication explicitly.
+Replication checking is disabled (``check_vma=False``) because Pallas
+calls inside shard_map bodies cannot declare varying-mesh-axes on their
+ShapeDtypeStruct outputs; the collectives and model layers are written
+rank-centric and manage replication explicitly.
 """
 from __future__ import annotations
 
@@ -14,12 +13,6 @@ __all__ = ["shard_map"]
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    if hasattr(jax, "shard_map"):  # JAX >= 0.6
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )

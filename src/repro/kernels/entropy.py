@@ -1,23 +1,19 @@
 """Pallas TPU kernels for the entropy-coded wire stage (DESIGN.md §10).
 
 Same single-pass structure as the dense kernels in ``lorenzo.py`` — one
-``(TILE_ROWS, BLOCK)`` tile per grid step, a resident packed window, and
-an SMEM word-offset carry across the sequential grid — but each block's
-payload is packed at FOUR per-sub-block widths instead of one: block
-``i`` splits into ``entropy.SUBS`` sub-blocks of ``entropy.SUB`` elements
-and sub ``k`` occupies exactly ``SUB_WORDS_PER_BIT * bw_k`` words (SUB is
-a multiple of 32, so sub boundaries stay word-aligned and the dense
-packer's alignment argument carries over unchanged).
+``(TILE_ROWS, BLOCK)`` tile per grid step, the HBM wire stream moved in
+line windows by DMA, and an SMEM word-offset carry across the sequential
+grid — but each block's payload is packed at FOUR per-sub-block widths
+instead of one: block ``i`` splits into ``SUBS`` sub-blocks of ``SUB``
+elements and sub ``k`` occupies exactly ``SUB // 32 * bw_k`` words (SUB
+is a multiple of 32, so sub boundaries stay word-aligned and the dense
+packer's alignment argument carries over unchanged).  The row packer and
+unpacker are the dense kernels' own, called with four width columns.
 
 The four 6-bit sub-widths travel packed into one int32 descriptor in the
 ``Compressed.bitwidth`` slot, so the tile's worst case is still
-``TILE_ROWS * BLOCK`` words and the dense kernels' PACK_PAD window and
-dump-tail overflow clamp apply verbatim.
-
-Per-element widths/offsets are computed with a static unroll over the
-``SUBS`` sub indices (one-hot sums) rather than a gather: TPU vector
-lanes hate data-dependent gathers, and with SUBS=4 the unroll is four
-masked adds.
+``TILE_ROWS * BLOCK`` words and the dense kernels' window and dump-tail
+overflow clamp apply verbatim.
 
 A static ``lossless`` flag swaps the error-bounded quantizer for a
 bit-exact ``bitcast(f32)->int32`` front end; everything downstream
@@ -34,20 +30,32 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.lorenzo import (
     BLOCK,
-    PACK_PAD_WORDS,
     TILE_ROWS,
+    _ANY,
+    _SEQUENTIAL,
+    _bitwidth,
+    _block_words,
+    _emit_tile,
+    _fetch_tile,
+    _pack_rows,
+    _pack_scratch,
     _row_spec,
     _scalar_spec,
-    _width_mask,
+    _umax,
+    _unpack_rows,
+    _unpack_scratch,
+    _unzigzag_cumsum,
+    _zigzag_tile,
+    LANES,
+    stream_lines,
+    to_lines,
 )
 
 SUBS = 4
 SUB = BLOCK // SUBS
-SUB_WORDS_PER_BIT = SUB // 32
 _DESC_BITS = 6
 
 
@@ -58,156 +66,64 @@ def _codes_tile(x, recip, lossless):
         q = jax.lax.bitcast_convert_type(x, jnp.int32)
     else:
         q = jnp.rint(x * recip).astype(jnp.int32)
-    col = jax.lax.broadcasted_iota(jnp.int32, q.shape, 1)
-    prev = jnp.where(col == 0, q, jnp.roll(q, 1, axis=1))
-    d = q - prev
-    zig = ((d << 1) ^ (d >> 31)).astype(jnp.uint32)
-    return zig, q[:, :1]
+    return _zigzag_tile(q)
 
 
 def _sub_widths_tile(zig):
-    """(TILE_ROWS, BLOCK) zigzag codes -> (TILE_ROWS, SUBS) int32 widths.
-
-    Masked per-sub maxima via a static unroll — no reshape of the lane
-    dimension, no gather.
-    """
-    j = jax.lax.broadcasted_iota(jnp.int32, (TILE_ROWS, BLOCK), 1)
-    sub_idx = j // SUB
-    powers = (jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)).astype(jnp.uint32)
-    widths = []
-    for k in range(SUBS):
-        umax_k = jnp.max(jnp.where(sub_idx == k, zig, jnp.uint32(0)), axis=1)
-        widths.append(
-            jnp.sum((umax_k[:, None] >= powers[None, :]).astype(jnp.int32), axis=1)
-        )
-    return jnp.stack(widths, axis=1)
+    """(TILE_ROWS, BLOCK) zigzag codes -> SUBS (TILE_ROWS, 1) int32 width
+    columns.  Masked per-sub maxima via a static unroll — no reshape of
+    the lane dimension, no gather."""
+    sub_idx = jax.lax.broadcasted_iota(jnp.int32, (TILE_ROWS, BLOCK), 1) // SUB
+    return [_bitwidth(_umax(jnp.where(sub_idx == k, zig, jnp.uint32(0)), 1))
+            for k in range(SUBS)]
 
 
 def _make_desc_col(sub_bw):
-    desc = sub_bw[:, 0]
+    desc = sub_bw[0]
     for k in range(1, SUBS):
-        desc = desc | (sub_bw[:, k] << (_DESC_BITS * k))
-    return desc[:, None]
+        desc = desc | (sub_bw[k] << (_DESC_BITS * k))
+    return desc
 
 
 def _split_desc_col(desc_col):
     mask = (1 << _DESC_BITS) - 1
-    return jnp.concatenate(
-        [(desc_col >> (_DESC_BITS * k)) & mask for k in range(SUBS)], axis=1
-    )
-
-
-def _entropy_tile_geometry(sub_bw):
-    """Tile-local per-element word / shift / width for the entropy layout.
-
-    ``sub_bw``: (TILE_ROWS, SUBS) int32.  Word offsets are exclusive
-    cumsums at sub then block granularity; per-element selection is a
-    one-hot sum over the SUBS static sub indices.
-    """
-    words_per_sub = sub_bw * SUB_WORDS_PER_BIT
-    words_per_block = jnp.sum(words_per_sub, axis=1)
-    block_off = jnp.cumsum(words_per_block) - words_per_block  # exclusive
-    sub_off = jnp.cumsum(words_per_sub, axis=1) - words_per_sub  # exclusive
-    j = jax.lax.broadcasted_iota(jnp.int32, (TILE_ROWS, BLOCK), 1)
-    sub_idx = j // SUB
-    jj = j - sub_idx * SUB
-    bw_el = jnp.zeros((TILE_ROWS, BLOCK), jnp.int32)
-    off_el = jnp.zeros((TILE_ROWS, BLOCK), jnp.int32)
-    for k in range(SUBS):
-        m = (sub_idx == k).astype(jnp.int32)
-        bw_el = bw_el + m * sub_bw[:, k:k + 1]
-        off_el = off_el + m * sub_off[:, k:k + 1]
-    bitpos = (block_off[:, None] + off_el) * 32 + jj * bw_el
-    word = bitpos >> 5
-    shift = (bitpos & 31).astype(jnp.uint32)
-    return word, shift, bw_el.astype(jnp.uint32), words_per_block
-
-
-def _entropy_pack_tile(zig, sub_bw, packed_ref, off_ref):
-    """Pack one tile at per-sub widths into the resident packed window,
-    advancing the SMEM word-offset carry (same clamp/dump-tail overflow
-    handling as the dense ``_pack_tile``)."""
-    word, shift, bwu, words_per_block = _entropy_tile_geometry(sub_bw)
-    u = zig & _width_mask(bwu)
-    lo = u << shift
-    hi = jnp.where(shift == 0, jnp.uint32(0),
-                   u >> jnp.minimum(32 - shift, jnp.uint32(31)))
-    fw = word.reshape(-1)
-    local = jnp.zeros((PACK_PAD_WORDS,), jnp.uint32)
-    local = local.at[fw].add(lo.reshape(-1))
-    local = local.at[fw + 1].add(hi.reshape(-1))
-
-    start = off_ref[0]
-    capacity = packed_ref.shape[0] - PACK_PAD_WORDS
-    s = jnp.minimum(start, capacity)
-    window = packed_ref[pl.ds(s, PACK_PAD_WORDS)]
-    packed_ref[pl.ds(s, PACK_PAD_WORDS)] = window | local
-    off_ref[0] = start + jnp.sum(words_per_block)
-
-
-def _entropy_unpack_tile(packed_ref, desc_col, off_ref):
-    """Gather + unpack one tile's segment at per-sub widths from the
-    resident packed window, advancing the SMEM carry."""
-    sub_bw = _split_desc_col(desc_col)
-    word, shift, bwu, words_per_block = _entropy_tile_geometry(sub_bw)
-    start = off_ref[0]
-    capacity = packed_ref.shape[0] - PACK_PAD_WORDS
-    s = jnp.minimum(start, capacity)
-    window = packed_ref[pl.ds(s, PACK_PAD_WORDS)]
-    lo = window[word] >> shift
-    hi = jnp.where(shift == 0, jnp.uint32(0),
-                   window[word + 1] << jnp.minimum(32 - shift, jnp.uint32(31)))
-    off_ref[0] = start + jnp.sum(words_per_block)
-    return (lo | hi) & _width_mask(bwu)
+    return [(desc_col >> (_DESC_BITS * k)) & mask for k in range(SUBS)]
 
 
 def _reconstruct(u, anchor_col, twoeb, lossless):
-    d = (u >> 1).astype(jnp.int32) ^ (-(u & 1).astype(jnp.int32))
-    q = anchor_col + jnp.cumsum(d, axis=1)
+    q = _unzigzag_cumsum(u, anchor_col)
     if lossless:
         return jax.lax.bitcast_convert_type(q, jnp.float32)
     return q.astype(jnp.float32) * twoeb
 
 
-def _quantize_pack_kernel(lossless, x_ref, recip_ref, packed_ref, desc_ref,
-                          anchor_ref, off_ref):
+def _quantize_pack_kernel(lossless, x_ref, recip_ref, _zeros, packed_ref,
+                          desc_ref, anchor_ref, *scratch):
     """quantize (or bitcast) + zigzag + entropy pack in one pass."""
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        packed_ref[...] = jnp.zeros_like(packed_ref[...])
-        off_ref[0] = 0
-
     zig, anchor = _codes_tile(x_ref[...], recip_ref[0, 0], lossless)
     sub_bw = _sub_widths_tile(zig)
     desc_ref[...] = _make_desc_col(sub_bw)
     anchor_ref[...] = anchor
-    _entropy_pack_tile(zig, sub_bw, packed_ref, off_ref)
+    _emit_tile(_pack_rows(zig, sub_bw), _block_words(sub_bw), packed_ref,
+               *scratch)
+
+
+def _unpack_codes(packed_ref, desc_ref, scratch):
+    sub_bw = _split_desc_col(desc_ref[...])
+    w = _fetch_tile(_block_words(sub_bw), packed_ref, *scratch)
+    return _unpack_rows(w, sub_bw)
 
 
 def _unpack_dequantize_kernel(lossless, packed_ref, desc_ref, anchor_ref,
-                              twoeb_ref, out_ref, off_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        off_ref[0] = 0
-
-    u = _entropy_unpack_tile(packed_ref, desc_ref[...], off_ref)
+                              twoeb_ref, out_ref, *scratch):
+    u = _unpack_codes(packed_ref, desc_ref, scratch)
     out_ref[...] = _reconstruct(u, anchor_ref[...], twoeb_ref[0, 0], lossless)
 
 
 def _unpack_dequantize_reduce_kernel(lossless, packed_ref, desc_ref,
                                      anchor_ref, twoeb_ref, acc_ref, out_ref,
-                                     off_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        off_ref[0] = 0
-
-    u = _entropy_unpack_tile(packed_ref, desc_ref[...], off_ref)
+                                     *scratch):
+    u = _unpack_codes(packed_ref, desc_ref, scratch)
     out_ref[...] = acc_ref[...] + _reconstruct(
         u, anchor_ref[...], twoeb_ref[0, 0], lossless
     )
@@ -238,25 +154,23 @@ def quantize_pack(
     """
     n_blocks = x2d.shape[0]
     recip, _ = _eb_scalars(eb, lossless)
-    cap_pad = capacity_words + PACK_PAD_WORDS
+    lines = stream_lines(capacity_words)
     packed, desc, anchor = pl.pallas_call(
         functools.partial(_quantize_pack_kernel, lossless),
         grid=(n_blocks // TILE_ROWS,),
-        in_specs=[_row_spec(BLOCK), _scalar_spec()],
-        out_specs=[
-            pl.BlockSpec((cap_pad,), lambda i: (0,)),
-            _row_spec(1),
-            _row_spec(1),
-        ],
+        in_specs=[_row_spec(BLOCK), _scalar_spec(), _ANY],
+        out_specs=[_ANY, _row_spec(1), _row_spec(1)],
         out_shape=[
-            jax.ShapeDtypeStruct((cap_pad,), jnp.uint32),
+            jax.ShapeDtypeStruct((lines, LANES), jnp.uint32),
             jax.ShapeDtypeStruct((n_blocks, 1), jnp.int32),
             jax.ShapeDtypeStruct((n_blocks, 1), jnp.int32),
         ],
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        scratch_shapes=_pack_scratch(),
+        input_output_aliases={2: 0},
+        compiler_params=_SEQUENTIAL,
         interpret=interpret,
-    )(x2d, recip)
-    return packed[:capacity_words], desc[:, 0], anchor[:, 0]
+    )(x2d, recip, jnp.zeros((lines, LANES), jnp.uint32))
+    return packed.reshape(-1)[:capacity_words], desc[:, 0], anchor[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("lossless", "interpret"))
@@ -267,22 +181,16 @@ def unpack_dequantize(
     """Entropy stream -> f32 (n_blocks, BLOCK), no accumulator."""
     n_blocks = desc.shape[0]
     _, twoeb = _eb_scalars(eb, lossless)
-    cap_pad = packed.shape[0] + PACK_PAD_WORDS
-    packed_pad = jnp.zeros((cap_pad,), jnp.uint32).at[: packed.shape[0]].set(packed)
     return pl.pallas_call(
         functools.partial(_unpack_dequantize_kernel, lossless),
         grid=(n_blocks // TILE_ROWS,),
-        in_specs=[
-            pl.BlockSpec((cap_pad,), lambda i: (0,)),
-            _row_spec(1),
-            _row_spec(1),
-            _scalar_spec(),
-        ],
+        in_specs=[_ANY, _row_spec(1), _row_spec(1), _scalar_spec()],
         out_specs=_row_spec(BLOCK),
         out_shape=jax.ShapeDtypeStruct((n_blocks, BLOCK), jnp.float32),
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        scratch_shapes=_unpack_scratch(),
+        compiler_params=_SEQUENTIAL,
         interpret=interpret,
-    )(packed_pad, desc[:, None], anchor[:, None], twoeb)
+    )(to_lines(packed), desc[:, None], anchor[:, None], twoeb)
 
 
 @functools.partial(jax.jit, static_argnames=("lossless", "interpret"))
@@ -294,20 +202,14 @@ def unpack_dequantize_reduce(
     """Entropy stream + acc -> acc + decompressed f32 (n_blocks, BLOCK)."""
     n_blocks = acc.shape[0]
     _, twoeb = _eb_scalars(eb, lossless)
-    cap_pad = packed.shape[0] + PACK_PAD_WORDS
-    packed_pad = jnp.zeros((cap_pad,), jnp.uint32).at[: packed.shape[0]].set(packed)
     return pl.pallas_call(
         functools.partial(_unpack_dequantize_reduce_kernel, lossless),
         grid=(n_blocks // TILE_ROWS,),
-        in_specs=[
-            pl.BlockSpec((cap_pad,), lambda i: (0,)),
-            _row_spec(1),
-            _row_spec(1),
-            _scalar_spec(),
-            _row_spec(BLOCK),
-        ],
+        in_specs=[_ANY, _row_spec(1), _row_spec(1), _scalar_spec(),
+                  _row_spec(BLOCK)],
         out_specs=_row_spec(BLOCK),
         out_shape=jax.ShapeDtypeStruct((n_blocks, BLOCK), jnp.float32),
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        scratch_shapes=_unpack_scratch(),
+        compiler_params=_SEQUENTIAL,
         interpret=interpret,
-    )(packed_pad, desc[:, None], anchor[:, None], twoeb, acc)
+    )(to_lines(packed), desc[:, None], anchor[:, None], twoeb, acc)

@@ -1,6 +1,6 @@
 """Pallas TPU kernels for the cuSZp-adapted block compressor.
 
-Six kernels, each tiled ``(TILE_ROWS, BLOCK)`` over a grid of block-rows:
+Kernels, each tiled ``(TILE_ROWS, BLOCK)`` over a grid of block-rows:
 
   * ``quantize``          f32 -> zigzag codes + per-block bitwidth
   * ``dequantize``        codes -> f32 (per-block prefix-sum reconstruct)
@@ -28,12 +28,24 @@ sequential TPU grid in SMEM scratch (no global cumsum, no second pass).
 The byte stream is IDENTICAL to ``bitpack.pack(quantize(x))`` — oracle-
 tested in tests/test_fused_pipeline.py.
 
+Wire stream in HBM (DESIGN.md §3.2).  The packed stream stays in HBM
+(``memory_space=pl.ANY``), viewed as ``(lines, LANES)`` uint32 so that
+every DMA moves whole 128-word lines.  A tile's segment starts at an
+arbitrary word offset ``start``: the pack side assembles it in a
+``(WIN_LINES, LANES)`` VMEM window at lane offset ``start % LANES``, ORs
+in the carried partial line of the previous tile, and DMAs the window to
+line ``start // LANES``; the unpack side DMAs the same window in.  Inside
+the window each block row is moved to or from its word offset by a flat
+roll (a dynamic sublane roll plus a dynamic lane roll), and the per-word
+/ per-element bit shuffles are lane gathers within one 128-lane vreg.
+No scatter, no resident capacity-sized block.
+
 TPU tiling notes (DESIGN.md §2): BLOCK=256 keeps each Lorenzo block two
 128-lane vregs wide; TILE_ROWS=8 gives an (8, 256) f32 tile = 8 KiB VMEM in,
 8 KiB out, well under VMEM while a multiple of the (8, 128) f32 native tile.
-The per-block cumsum is a lane-wise prefix sum on the VPU; blocks are
-independent so there is no cross-tile carry — this is what replaces cuSZp's
-per-warp layout on the MXU-less part of the chip.
+The per-block cumsum is a log-step lane-roll prefix sum on the VPU; blocks
+are independent so there is no cross-tile carry — this is what replaces
+cuSZp's per-warp layout on the MXU-less part of the chip.
 
 The scalar error bound arrives as a (1, 1) operand mapped to every grid
 cell (index_map -> (0, 0)) rather than a closure constant, so one compiled
@@ -50,31 +62,63 @@ from jax.experimental.pallas import tpu as pltpu
 
 BLOCK = 256
 TILE_ROWS = 8
-# Fused-pack geometry: BLOCK % 32 == 0 makes every block's packed payload a
-# whole number of words (bw words per 32 elements), so one (TILE_ROWS, BLOCK)
-# tile emits at most TILE_ROWS * BLOCK words (all blocks at bw=32).
-WORDS_PER_BIT = BLOCK // 32
-TILE_WORDS = TILE_ROWS * BLOCK
-# Window slack: a tile's clamped read-modify-write window is TILE_WORDS + 1
-# words (the +1 absorbs the always-zero straddle word of the last element).
-PACK_PAD_WORDS = TILE_WORDS + 1
+LANES = 128
+# A tile emits at most TILE_ROWS * BLOCK = 2048 words; placed at a lane
+# offset of up to LANES - 1 that spans 17 lines.  24 keeps the window a
+# whole number of (8, 128) vregs.
+WIN_LINES = 24
+_SIGN = 0x80000000
 
 
-def _bitwidth(umax_keepdims: jnp.ndarray) -> jnp.ndarray:
+def _umax(u, axis):
+    """Unsigned max: Mosaic reduces only signed integers, so flip the sign
+    bit (an order-preserving map from uint32 onto int32) around the max."""
+    s = jax.lax.bitcast_convert_type(u ^ jnp.uint32(_SIGN), jnp.int32)
+    m = jnp.max(s, axis=axis, keepdims=True)
+    return jax.lax.bitcast_convert_type(m, jnp.uint32) ^ jnp.uint32(_SIGN)
+
+
+def _bitwidth(umax_col: jnp.ndarray) -> jnp.ndarray:
+    """(R, 1) uint32 maxima -> (R, 1) int32 bits needed."""
     powers = (jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)).astype(jnp.uint32)
-    return jnp.sum((umax_keepdims >= powers[None, :]).astype(jnp.int32), axis=-1,
+    return jnp.sum((umax_col >= powers[None, :]).astype(jnp.int32), axis=-1,
                    keepdims=True)
+
+
+def _row_cumsum(d):
+    """Inclusive prefix sum along lanes as log-step roll-and-add (Mosaic
+    has no cumsum).  int32 addition wraps, so the result equals
+    ``jnp.cumsum`` bit for bit."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
+
+    def step(i, d):
+        s = jnp.left_shift(jnp.int32(1), i)
+        return d + jnp.where(lane >= s, pltpu.roll(d, s, 1), 0)
+
+    return jax.lax.fori_loop(0, d.shape[1].bit_length() - 1, step, d)
+
+
+def _zigzag_tile(q):
+    """int32 tile -> (zigzag Lorenzo deltas, anchor col)."""
+    col = jax.lax.broadcasted_iota(jnp.int32, q.shape, 1)
+    prev = jnp.where(col == 0, q, pltpu.roll(q, 1, 1))
+    d = q - prev  # first column is 0; absolute value goes out via anchor
+    return ((d << 1) ^ (d >> 31)).astype(jnp.uint32), q[:, :1]
 
 
 def _quantize_tile(x, recip):
     """Shared quantization math: f32 tile -> (zigzag codes, bw col, anchor col)."""
-    q = jnp.rint(x * recip).astype(jnp.int32)
-    col = jax.lax.broadcasted_iota(jnp.int32, q.shape, 1)
-    prev = jnp.where(col == 0, q, jnp.roll(q, 1, axis=1))
-    d = q - prev  # first column is 0; absolute value goes out via anchor
-    zig = ((d << 1) ^ (d >> 31)).astype(jnp.uint32)
-    umax = jnp.max(zig, axis=1)  # (TILE_ROWS,)
-    return zig, _bitwidth(umax[:, None]), q[:, :1]
+    zig, anchor = _zigzag_tile(jnp.rint(x * recip).astype(jnp.int32))
+    return zig, _bitwidth(_umax(zig, 1)), anchor
+
+
+def _unzigzag_cumsum(u, anchor_col):
+    d = (u >> 1).astype(jnp.int32) ^ (-(u & 1).astype(jnp.int32))
+    return anchor_col + _row_cumsum(d)
+
+
+def _reconstruct(u, anchor_col, twoeb):
+    return _unzigzag_cumsum(u, anchor_col).astype(jnp.float32) * twoeb
 
 
 def _quantize_kernel(x_ref, recip_ref, codes_ref, bw_ref, anchor_ref):
@@ -85,17 +129,13 @@ def _quantize_kernel(x_ref, recip_ref, codes_ref, bw_ref, anchor_ref):
 
 
 def _dequantize_kernel(codes_ref, anchor_ref, twoeb_ref, x_ref):
-    u = codes_ref[...]
-    d = (u >> 1).astype(jnp.int32) ^ (-(u & 1).astype(jnp.int32))
-    q = anchor_ref[...] + jnp.cumsum(d, axis=1)
-    x_ref[...] = q.astype(jnp.float32) * twoeb_ref[0, 0]
+    x_ref[...] = _reconstruct(codes_ref[...], anchor_ref[...], twoeb_ref[0, 0])
 
 
 def _dequantize_reduce_kernel(codes_ref, anchor_ref, twoeb_ref, acc_ref, out_ref):
-    u = codes_ref[...]
-    d = (u >> 1).astype(jnp.int32) ^ (-(u & 1).astype(jnp.int32))
-    q = anchor_ref[...] + jnp.cumsum(d, axis=1)
-    out_ref[...] = acc_ref[...] + q.astype(jnp.float32) * twoeb_ref[0, 0]
+    out_ref[...] = acc_ref[...] + _reconstruct(
+        codes_ref[...], anchor_ref[...], twoeb_ref[0, 0]
+    )
 
 
 def _scalar_spec():
@@ -104,6 +144,11 @@ def _scalar_spec():
 
 def _row_spec(width):
     return pl.BlockSpec((TILE_ROWS, width), lambda i: (i, 0))
+
+
+_ANY = pl.BlockSpec(memory_space=pl.ANY)
+# The wire-stream carries walk the grid in order.
+_SEQUENTIAL = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -149,173 +194,319 @@ def dequantize(
 
 # ---------------------------------------------------------------------------
 # Fused compression pipeline (DESIGN.md §3)
+#
+# The helpers below serve both wire formats.  A block row splits into
+# ``len(sub_bw)`` equal sub-blocks, sub ``k`` packed at width
+# ``sub_bw[k]`` (a (TILE_ROWS, 1) int32 column); the dense format is the
+# one-sub case, the entropy format (kernels/entropy.py) the four-sub one.
 # ---------------------------------------------------------------------------
 
 
-def _tile_pack_geometry(bw_col):
-    """Per-element word index / shift / width for one tile, tile-local.
-
-    ``bw_col``: (TILE_ROWS, 1) int32.  Returns (word, shift, bwu, words_per
-    _block) where ``word`` indexes into the tile's own word segment (blocks
-    are word-aligned, so the segment starts at word 0 of the tile).
-    """
-    bwf = bw_col[:, 0]
-    words_per_block = bwf * WORDS_PER_BIT
-    local_off = jnp.cumsum(words_per_block) - words_per_block  # exclusive
-    j = jax.lax.broadcasted_iota(jnp.int32, (TILE_ROWS, BLOCK), 1)
-    bitpos = local_off[:, None] * 32 + j * bwf[:, None]
-    word = bitpos >> 5
-    shift = (bitpos & 31).astype(jnp.uint32)
-    bwu = jnp.broadcast_to(bwf[:, None], (TILE_ROWS, BLOCK)).astype(jnp.uint32)
-    return word, shift, bwu, words_per_block
-
-
-def _width_mask(bwu):
+def _width_mask(bw):
+    """int32 widths -> uint32 masks of that many low bits (integer min:
+    Mosaic has no unsigned min)."""
     return jnp.where(
-        bwu == 0,
+        bw == 0,
         jnp.uint32(0),
-        jnp.uint32(0xFFFFFFFF) >> jnp.minimum(32 - bwu, jnp.uint32(31)),
+        jnp.uint32(0xFFFFFFFF) >> jnp.minimum(32 - bw, 31).astype(jnp.uint32),
     )
 
 
-def _pack_tile(zig, bw, packed_ref, off_ref):
-    """Pack one tile's zigzag codes into the resident packed-output window,
-    advancing the SMEM word-offset carry.
+def _sub_geometry(sub_bw):
+    """-> (sub size, words per bit of width, word offset col of each sub)."""
+    sub = BLOCK // len(sub_bw)
+    wpb = sub // 32
+    offs, acc = [], jnp.zeros_like(sub_bw[0])
+    for b in sub_bw:
+        offs.append(acc)
+        acc = acc + wpb * b
+    return sub, wpb, offs
 
-    The word offset of the current tile is carried in SMEM scratch across
-    the sequential grid; the packed output block has a constant index map,
-    so it stays resident while every tile ORs its word-aligned segment in
-    (disjoint bit ranges => OR == ADD, same argument as bitpack.pack).
-    Overflow past the true capacity lands in the PACK_PAD_WORDS dump tail,
-    which the wrapper slices off — never silent corruption of valid words.
+
+def _block_words(sub_bw):
+    """(TILE_ROWS, 1) int32 packed words of each block row."""
+    sub, wpb, offs = _sub_geometry(sub_bw)
+    return offs[-1] + wpb * sub_bw[-1]
+
+
+def _take(lo, hi, idx):
+    """Lane gather ``row[idx]`` from a 256-lane row held as two 128-lane
+    halves; Mosaic gathers within one vreg only."""
+    def half(ix):
+        k = ix & (LANES - 1)
+        g_lo = jnp.take_along_axis(lo, k, axis=1, mode="promise_in_bounds")
+        g_hi = jnp.take_along_axis(hi, k, axis=1, mode="promise_in_bounds")
+        return jnp.where(ix < LANES, g_lo, g_hi)
+
+    return jnp.concatenate([half(idx[:, :LANES]), half(idx[:, LANES:])], axis=1)
+
+
+def _select_sub(sub_bw, offs, sub, lane):
+    """Per-lane (width, word offset) of the sub-block each element lane is in."""
+    b = jnp.zeros(lane.shape, jnp.int32)
+    off = jnp.zeros(lane.shape, jnp.int32)
+    for k, (bk, ok) in enumerate(zip(sub_bw, offs)):
+        m = (lane >= k * sub) & (lane < (k + 1) * sub)
+        b = jnp.where(m, bk, b)
+        off = jnp.where(m, ok, off)
+    return b, off
+
+
+def _pack_rows(u, sub_bw):
+    """(TILE_ROWS, BLOCK) codes -> (TILE_ROWS, BLOCK) words: row r holds
+    block r's packed words in lanes [0, words_r), zeros after.  Every
+    code must fit its sub-block's width (the widths come from the codes'
+    own maxima).
+
+    Word ``k`` of a sub at width ``b`` gathers the elements overlapping
+    its 32 bits — element ``floor(32 k / b) + m`` for ``m`` up to
+    ``ceil(32 / b) + 1`` — so the loop runs as many rounds as the
+    narrowest non-zero width in the tile needs.
     """
-    word, shift, bwu, words_per_block = _tile_pack_geometry(bw)
-    u = zig & _width_mask(bwu)
-    lo = u << shift
-    hi = jnp.where(shift == 0, jnp.uint32(0),
-                   u >> jnp.minimum(32 - shift, jnp.uint32(31)))
-    # Tile-local dense segment: scatter-add over <= TILE_WORDS words.  The
-    # +1 slot absorbs the last element's always-zero straddle word.
-    fw = word.reshape(-1)
-    local = jnp.zeros((PACK_PAD_WORDS,), jnp.uint32)
-    local = local.at[fw].add(lo.reshape(-1))
-    local = local.at[fw + 1].add(hi.reshape(-1))
+    sub, wpb, offs = _sub_geometry(sub_bw)
+    lane = jax.lax.broadcasted_iota(jnp.int32, u.shape, 1)
+    # Per output word: width, first element of its sub, sub-local word.
+    b = jnp.zeros(u.shape, jnp.int32)
+    base = jnp.zeros(u.shape, jnp.int32)
+    kk = lane
+    valid = jnp.zeros(u.shape, jnp.bool_)
+    for k, (bk, ok) in enumerate(zip(sub_bw, offs)):
+        m = (lane >= ok) & (lane < ok + wpb * bk)
+        b = jnp.where(m, bk, b)
+        base = jnp.where(m, k * sub, base)
+        kk = jnp.where(m, lane - ok, kk)
+        valid = valid | m
+    j0 = (32 * kk) // jnp.maximum(b, 1)
+    rounds = jnp.zeros_like(sub_bw[0])
+    for bk in sub_bw:
+        rounds = jnp.maximum(
+            rounds, jnp.where(bk > 0, (31 + bk) // jnp.maximum(bk, 1) + 1, 0))
+    lo, hi = u[:, :LANES], u[:, LANES:]
 
-    start = off_ref[0]
-    capacity = packed_ref.shape[0] - PACK_PAD_WORDS
-    s = jnp.minimum(start, capacity)  # overflowing tiles write the dump tail
-    window = packed_ref[pl.ds(s, PACK_PAD_WORDS)]
-    packed_ref[pl.ds(s, PACK_PAD_WORDS)] = window | local
-    off_ref[0] = start + jnp.sum(words_per_block)
+    def body(m, acc):
+        jrel = j0 + m
+        sh = jrel * b - 32 * kk  # element's bit offset relative to the word
+        g = _take(lo, hi, jnp.minimum(base + jrel, BLOCK - 1))
+        left = g << jnp.clip(sh, 0, 31).astype(jnp.uint32)
+        right = g >> jnp.clip(-sh, 0, 31).astype(jnp.uint32)
+        part = jnp.where(sh >= 0, left, right)
+        return acc | jnp.where(valid & (sh < 32), part, jnp.uint32(0))
+
+    return jax.lax.fori_loop(0, jnp.max(rounds), body, jnp.zeros_like(u))
 
 
-def _quantize_pack_kernel(x_ref, recip_ref, packed_ref, bw_ref, anchor_ref,
-                          off_ref):
-    """quantize + zigzag + bitpack in one pass over the tile."""
+def _unpack_rows(w, sub_bw):
+    """Inverse of :func:`_pack_rows`: block-aligned word rows -> codes."""
+    sub, wpb, offs = _sub_geometry(sub_bw)
+    lane = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1)
+    b, off = _select_sub(sub_bw, offs, sub, lane)
+    bitpos = off * 32 + (lane % sub) * b
+    word = bitpos >> 5
+    shift = bitpos & 31
+    lo, hi = w[:, :LANES], w[:, LANES:]
+    first = _take(lo, hi, word) >> shift.astype(jnp.uint32)
+    second = _take(lo, hi, jnp.minimum(word + 1, BLOCK - 1))
+    straddle = jnp.where(shift == 0, jnp.uint32(0),
+                         second << (32 - jnp.maximum(shift, 1)).astype(jnp.uint32))
+    return (first | straddle) & _width_mask(b)
+
+
+def _words_of_row(words_col, r):
+    """Scalar words of block row ``r`` (traced) of a (TILE_ROWS, 1) column."""
+    row = jax.lax.broadcasted_iota(jnp.int32, words_col.shape, 0)
+    return jnp.sum(jnp.where(row == r, words_col, 0))
+
+
+def _flat_roll(x, s):
+    """Roll a (lines, LANES) window by ``s`` words toward higher flat
+    indices (``s`` a non-negative scalar below the window size)."""
+    a, c = s // LANES, s % LANES
+    z = pltpu.roll(pltpu.roll(x, a, 0), c, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(lane >= c, z, pltpu.roll(z, 1, 0))
+
+
+def _window_start(start, hbm_ref):
+    """Clamp a tile's word offset into the stream: a tile past the
+    provisioned capacity reads/writes the WIN_LINES dump tail, never a
+    valid word."""
+    cap_lines = hbm_ref.shape[0] - WIN_LINES
+    s = jnp.minimum(start, cap_lines * LANES)
+    return s // LANES, s % LANES
+
+
+def _emit_tile(words, words_col, hbm_ref, off_ref, carry_ref, seg_ref, sem):
+    """Append one tile's packed rows to the HBM wire stream.
+
+    Rows are placed back to back at the carried word offset inside a
+    window that starts on the offset's line; line 0 is ORed with the
+    previous tile's partial last line (blocks are word-aligned, so OR ==
+    concatenation).  The window DMA waits for the previous one: they
+    overlap on that line, and the window buffer is reused.
+    """
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _():
-        packed_ref[...] = jnp.zeros_like(packed_ref[...])
+        off_ref[0] = 0
+        carry_ref[...] = jnp.zeros_like(carry_ref)
+
+    start = off_ref[0]
+    line0, b0 = _window_start(start, hbm_ref)
+    line = jax.lax.broadcasted_iota(jnp.int32, seg_ref.shape, 0)
+    lo, hi = words[:, :LANES], words[:, LANES:]
+
+    def place(r, carry):  # row r's two lines, rolled to its word offset
+        seg, off = carry
+        pick = lambda half: pltpu.roll(half, (TILE_ROWS - r) % TILE_ROWS, 0)[:1]
+        placed = jnp.where(line == 0, pick(lo),
+                           jnp.where(line == 1, pick(hi), jnp.uint32(0)))
+        return (seg | _flat_roll(placed, b0 + off),
+                off + _words_of_row(words_col, r))
+
+    seg, total = jax.lax.fori_loop(
+        0, TILE_ROWS, place,
+        (jnp.where(line == 0, carry_ref[...], jnp.uint32(0)), jnp.int32(0)))
+    end = (b0 + total) // LANES
+    carry_ref[...] = pltpu.roll(seg, (WIN_LINES - end) % WIN_LINES, 0)[:1]
+    copy = pltpu.make_async_copy(
+        seg_ref, hbm_ref.at[pl.ds(line0, WIN_LINES)], sem)
+
+    @pl.when(i > 0)
+    def _():
+        copy.wait()  # the previous tile's window (same shape and semaphore)
+
+    seg_ref[...] = seg
+    copy.start()
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _():
+        copy.wait()
+
+    off_ref[0] = start + total
+
+
+def _fetch_tile(words_col, hbm_ref, off_ref, win_ref, sem):
+    """Read one tile's block rows from the HBM wire stream, each moved to
+    lane 0 of its row: -> (TILE_ROWS, BLOCK) words for ``_unpack_rows``."""
+    @pl.when(pl.program_id(0) == 0)
+    def _():
         off_ref[0] = 0
 
+    start = off_ref[0]
+    line0, b0 = _window_start(start, hbm_ref)
+    copy = pltpu.make_async_copy(
+        hbm_ref.at[pl.ds(line0, WIN_LINES)], win_ref, sem)
+    copy.start()
+    copy.wait()
+    win = win_ref[...]
+    size = WIN_LINES * LANES
+    row = jax.lax.broadcasted_iota(jnp.int32, (TILE_ROWS, LANES), 0)
+
+    def take(r, carry):  # the window rolled back to row r's word offset
+        lo, hi, off = carry
+        y = _flat_roll(win, (size - b0 - off) % size)
+        return (jnp.where(row == r, y[0:1], lo), jnp.where(row == r, y[1:2], hi),
+                off + _words_of_row(words_col, r))
+
+    zeros = jnp.zeros((TILE_ROWS, LANES), jnp.uint32)
+    lo, hi, total = jax.lax.fori_loop(0, TILE_ROWS, take,
+                                      (zeros, zeros, jnp.int32(0)))
+    off_ref[0] = start + total
+    return jnp.concatenate([lo, hi], axis=1)
+
+
+def _pack_scratch():
+    """off carry, partial-line carry, window buffer, DMA semaphore."""
+    return [
+        pltpu.SMEM((1,), jnp.int32),
+        pltpu.VMEM((1, LANES), jnp.uint32),
+        pltpu.VMEM((WIN_LINES, LANES), jnp.uint32),
+        pltpu.SemaphoreType.DMA(()),
+    ]
+
+
+def _unpack_scratch():
+    """off carry, window buffer, DMA semaphore."""
+    return [
+        pltpu.SMEM((1,), jnp.int32),
+        pltpu.VMEM((WIN_LINES, LANES), jnp.uint32),
+        pltpu.SemaphoreType.DMA(()),
+    ]
+
+
+def stream_lines(capacity_words: int) -> int:
+    """Lines of the (lines, LANES) HBM view of a stream of this capacity,
+    including the WIN_LINES dump tail."""
+    return -(-capacity_words // LANES) + WIN_LINES
+
+
+def to_lines(packed: jnp.ndarray) -> jnp.ndarray:
+    """Flat wire stream -> zero-padded (lines, LANES) view for the kernels."""
+    n = packed.shape[0]
+    return jnp.pad(packed, (0, stream_lines(n) * LANES - n)).reshape(-1, LANES)
+
+
+def _quantize_pack_kernel(x_ref, recip_ref, _zeros, packed_ref, bw_ref,
+                          anchor_ref, *scratch):
+    """quantize + zigzag + bitpack in one pass over the tile."""
     zig, bw, anchor = _quantize_tile(x_ref[...], recip_ref[0, 0])
     bw_ref[...] = bw
     anchor_ref[...] = anchor
-    _pack_tile(zig, bw, packed_ref, off_ref)
-
-
-def _unpack_tile(packed_ref, bw, off_ref):
-    """Gather + unpack one tile's word-aligned segment from the resident
-    packed window, advancing the SMEM word-offset carry.  Returns the
-    tile's zigzag codes (TILE_ROWS, BLOCK) without materializing them in
-    HBM."""
-    word, shift, bwu, words_per_block = _tile_pack_geometry(bw)
-    start = off_ref[0]
-    capacity = packed_ref.shape[0] - PACK_PAD_WORDS
-    s = jnp.minimum(start, capacity)
-    window = packed_ref[pl.ds(s, PACK_PAD_WORDS)]
-    lo = window[word] >> shift
-    hi = jnp.where(shift == 0, jnp.uint32(0),
-                   window[word + 1] << jnp.minimum(32 - shift, jnp.uint32(31)))
-    off_ref[0] = start + jnp.sum(words_per_block)
-    return (lo | hi) & _width_mask(bwu)
-
-
-def _reconstruct(u, anchor_col, twoeb):
-    d = (u >> 1).astype(jnp.int32) ^ (-(u & 1).astype(jnp.int32))
-    q = anchor_col + jnp.cumsum(d, axis=1)
-    return q.astype(jnp.float32) * twoeb
+    _emit_tile(_pack_rows(zig, [bw]), _block_words([bw]), packed_ref,
+               *scratch)
 
 
 def _unpack_dequantize_reduce_kernel(packed_ref, bw_ref, anchor_ref, twoeb_ref,
-                                     acc_ref, out_ref, off_ref):
+                                     acc_ref, out_ref, *scratch):
     """Inverse fusion: packed words + acc -> acc + dequantize(unpack(words)).
 
-    Same SMEM word-offset carry as the pack kernel; the tile gathers its
-    word-aligned segment from a resident window, so the uint32 codes array
-    never materializes in HBM on the receive side either.
+    Same SMEM word-offset carry as the pack kernel; the tile DMAs its
+    word window from the HBM stream, so the uint32 codes array never
+    materializes in HBM on the receive side either.
     """
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        off_ref[0] = 0
-
-    u = _unpack_tile(packed_ref, bw_ref[...], off_ref)
-    out_ref[...] = acc_ref[...] + _reconstruct(u, anchor_ref[...],
-                                               twoeb_ref[0, 0])
+    bw = bw_ref[...]
+    w = _fetch_tile(_block_words([bw]), packed_ref, *scratch)
+    out_ref[...] = acc_ref[...] + _reconstruct(
+        _unpack_rows(w, [bw]), anchor_ref[...], twoeb_ref[0, 0])
 
 
 def _unpack_dequantize_kernel(packed_ref, bw_ref, anchor_ref, twoeb_ref,
-                              out_ref, off_ref):
+                              out_ref, *scratch):
     """Pure fused decompress (no accumulator): the allgather/scatter receive
     path, which would otherwise pay a zero-accumulator materialization."""
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        off_ref[0] = 0
-
-    u = _unpack_tile(packed_ref, bw_ref[...], off_ref)
-    out_ref[...] = _reconstruct(u, anchor_ref[...], twoeb_ref[0, 0])
+    bw = bw_ref[...]
+    w = _fetch_tile(_block_words([bw]), packed_ref, *scratch)
+    out_ref[...] = _reconstruct(_unpack_rows(w, [bw]), anchor_ref[...],
+                                twoeb_ref[0, 0])
 
 
 def _unpack_reduce_repack_kernel(emit_f32, packed_in_ref, bw_in_ref,
                                  anchor_in_ref, twoeb_ref, acc_ref, recip_ref,
-                                 *refs):
-    """The single-pass ring hop (DESIGN.md §3.1): per tile, gather the
-    received packed segment from the resident input window, unpack +
-    un-zigzag + prefix-sum + dequantize, add the local accumulator chunk,
-    then immediately re-quantize, zigzag and pack the updated chunk into
-    the resident outgoing wire window.  The f32 intermediate lives only in
-    VMEM (unless ``emit_f32`` — the redoub carry needs it); the outgoing
-    per-block bitwidths/anchors come out of the same pass.  Two SMEM
-    word-offset carries: one walking the received stream, one walking the
-    outgoing stream.
+                                 _zeros, *refs):
+    """The single-pass ring hop (DESIGN.md §3.1): per tile, fetch the
+    received packed segment, unpack + un-zigzag + prefix-sum + dequantize,
+    add the local accumulator chunk, then immediately re-quantize, zigzag
+    and pack the updated chunk onto the outgoing wire stream.  The f32
+    intermediate lives only in VMEM (unless ``emit_f32`` — the redoub
+    carry needs it); the outgoing per-block bitwidths/anchors come out of
+    the same pass.  Two SMEM word-offset carries: one walking the
+    received stream, one walking the outgoing stream.
     """
-    if emit_f32:
-        (packed_out_ref, bw_out_ref, anchor_out_ref, x_out_ref,
-         off_in_ref, off_out_ref) = refs
-    else:
-        (packed_out_ref, bw_out_ref, anchor_out_ref,
-         off_in_ref, off_out_ref) = refs
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        packed_out_ref[...] = jnp.zeros_like(packed_out_ref[...])
-        off_in_ref[0] = 0
-        off_out_ref[0] = 0
-
-    u = _unpack_tile(packed_in_ref, bw_in_ref[...], off_in_ref)
-    x = acc_ref[...] + _reconstruct(u, anchor_in_ref[...], twoeb_ref[0, 0])
+    n_out = 4 if emit_f32 else 3
+    outs, scratch = refs[:n_out], refs[n_out:]
+    packed_out_ref, bw_out_ref, anchor_out_ref = outs[:3]
+    bw_in = bw_in_ref[...]
+    w = _fetch_tile(_block_words([bw_in]), packed_in_ref, *scratch[4:])
+    x = acc_ref[...] + _reconstruct(_unpack_rows(w, [bw_in]),
+                                    anchor_in_ref[...], twoeb_ref[0, 0])
     zig, bw, anchor = _quantize_tile(x, recip_ref[0, 0])
     bw_out_ref[...] = bw
     anchor_out_ref[...] = anchor
     if emit_f32:
-        x_out_ref[...] = x
-    _pack_tile(zig, bw, packed_out_ref, off_out_ref)
+        outs[3][...] = x
+    _emit_tile(_pack_rows(zig, [bw]), _block_words([bw]), packed_out_ref,
+               *scratch[:4])
 
 
 @functools.partial(
@@ -349,16 +540,10 @@ def unpack_reduce_repack(
     n_blocks = acc.shape[0]
     twoeb = (2.0 * eb_in).reshape(1, 1).astype(jnp.float32)
     recip = (1.0 / (2.0 * eb_out)).reshape(1, 1).astype(jnp.float32)
-    cap_in_pad = packed.shape[0] + PACK_PAD_WORDS
-    packed_pad = jnp.zeros((cap_in_pad,), jnp.uint32).at[: packed.shape[0]].set(packed)
-    cap_out_pad = capacity_words + PACK_PAD_WORDS
-    out_specs = [
-        pl.BlockSpec((cap_out_pad,), lambda i: (0,)),
-        _row_spec(1),
-        _row_spec(1),
-    ]
+    out_lines = stream_lines(capacity_words)
+    out_specs = [_ANY, _row_spec(1), _row_spec(1)]
     out_shape = [
-        jax.ShapeDtypeStruct((cap_out_pad,), jnp.uint32),
+        jax.ShapeDtypeStruct((out_lines, LANES), jnp.uint32),
         jax.ShapeDtypeStruct((n_blocks, 1), jnp.int32),
         jax.ShapeDtypeStruct((n_blocks, 1), jnp.int32),
     ]
@@ -369,23 +554,26 @@ def unpack_reduce_repack(
         functools.partial(_unpack_reduce_repack_kernel, emit_f32),
         grid=(n_blocks // TILE_ROWS,),
         in_specs=[
-            pl.BlockSpec((cap_in_pad,), lambda i: (0,)),
+            _ANY,
             _row_spec(1),
             _row_spec(1),
             _scalar_spec(),
             _row_spec(BLOCK),
             _scalar_spec(),
+            _ANY,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32), pltpu.SMEM((1,), jnp.int32)],
+        scratch_shapes=_pack_scratch() + _unpack_scratch(),
+        input_output_aliases={6: 0},
+        compiler_params=_SEQUENTIAL,
         interpret=interpret,
-    )(packed_pad, bitwidth[:, None], anchor[:, None], twoeb, acc, recip)
+    )(to_lines(packed), bitwidth[:, None], anchor[:, None], twoeb, acc, recip,
+      jnp.zeros((out_lines, LANES), jnp.uint32))
+    packed_out = res[0].reshape(-1)[:capacity_words]
     if emit_f32:
-        packed_out, bw, anchor_out, x = res
-        return packed_out[:capacity_words], bw[:, 0], anchor_out[:, 0], x
-    packed_out, bw, anchor_out = res
-    return packed_out[:capacity_words], bw[:, 0], anchor_out[:, 0]
+        return packed_out, res[1][:, 0], res[2][:, 0], res[3]
+    return packed_out, res[1][:, 0], res[2][:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("capacity_words", "interpret"))
@@ -401,25 +589,23 @@ def quantize_pack(
     """
     n_blocks = x2d.shape[0]
     recip = (1.0 / (2.0 * eb)).reshape(1, 1).astype(jnp.float32)
-    cap_pad = capacity_words + PACK_PAD_WORDS
+    lines = stream_lines(capacity_words)
     packed, bw, anchor = pl.pallas_call(
         _quantize_pack_kernel,
         grid=(n_blocks // TILE_ROWS,),
-        in_specs=[_row_spec(BLOCK), _scalar_spec()],
-        out_specs=[
-            pl.BlockSpec((cap_pad,), lambda i: (0,)),
-            _row_spec(1),
-            _row_spec(1),
-        ],
+        in_specs=[_row_spec(BLOCK), _scalar_spec(), _ANY],
+        out_specs=[_ANY, _row_spec(1), _row_spec(1)],
         out_shape=[
-            jax.ShapeDtypeStruct((cap_pad,), jnp.uint32),
+            jax.ShapeDtypeStruct((lines, LANES), jnp.uint32),
             jax.ShapeDtypeStruct((n_blocks, 1), jnp.int32),
             jax.ShapeDtypeStruct((n_blocks, 1), jnp.int32),
         ],
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        scratch_shapes=_pack_scratch(),
+        input_output_aliases={2: 0},
+        compiler_params=_SEQUENTIAL,
         interpret=interpret,
-    )(x2d, recip)
-    return packed[:capacity_words], bw[:, 0], anchor[:, 0]
+    )(x2d, recip, jnp.zeros((lines, LANES), jnp.uint32))
+    return packed.reshape(-1)[:capacity_words], bw[:, 0], anchor[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -434,22 +620,16 @@ def unpack_dequantize(
     """Fused unpack + dequantize: packed stream -> f32 (n_blocks, BLOCK)."""
     n_blocks = bitwidth.shape[0]
     twoeb = (2.0 * eb).reshape(1, 1).astype(jnp.float32)
-    cap_pad = packed.shape[0] + PACK_PAD_WORDS
-    packed_pad = jnp.zeros((cap_pad,), jnp.uint32).at[: packed.shape[0]].set(packed)
     return pl.pallas_call(
         _unpack_dequantize_kernel,
         grid=(n_blocks // TILE_ROWS,),
-        in_specs=[
-            pl.BlockSpec((cap_pad,), lambda i: (0,)),
-            _row_spec(1),
-            _row_spec(1),
-            _scalar_spec(),
-        ],
+        in_specs=[_ANY, _row_spec(1), _row_spec(1), _scalar_spec()],
         out_specs=_row_spec(BLOCK),
         out_shape=jax.ShapeDtypeStruct((n_blocks, BLOCK), jnp.float32),
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        scratch_shapes=_unpack_scratch(),
+        compiler_params=_SEQUENTIAL,
         interpret=interpret,
-    )(packed_pad, bitwidth[:, None], anchor[:, None], twoeb)
+    )(to_lines(packed), bitwidth[:, None], anchor[:, None], twoeb)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -468,23 +648,17 @@ def unpack_dequantize_reduce(
     """
     n_blocks = acc.shape[0]
     twoeb = (2.0 * eb).reshape(1, 1).astype(jnp.float32)
-    cap_pad = packed.shape[0] + PACK_PAD_WORDS
-    packed_pad = jnp.zeros((cap_pad,), jnp.uint32).at[: packed.shape[0]].set(packed)
     return pl.pallas_call(
         _unpack_dequantize_reduce_kernel,
         grid=(n_blocks // TILE_ROWS,),
-        in_specs=[
-            pl.BlockSpec((cap_pad,), lambda i: (0,)),
-            _row_spec(1),
-            _row_spec(1),
-            _scalar_spec(),
-            _row_spec(BLOCK),
-        ],
+        in_specs=[_ANY, _row_spec(1), _row_spec(1), _scalar_spec(),
+                  _row_spec(BLOCK)],
         out_specs=_row_spec(BLOCK),
         out_shape=jax.ShapeDtypeStruct((n_blocks, BLOCK), jnp.float32),
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        scratch_shapes=_unpack_scratch(),
+        compiler_params=_SEQUENTIAL,
         interpret=interpret,
-    )(packed_pad, bitwidth[:, None], anchor[:, None], twoeb, acc)
+    )(to_lines(packed), bitwidth[:, None], anchor[:, None], twoeb, acc)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -15,6 +15,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import registry
 from repro.core.shmap import shard_map
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.training import make_setup
 from repro.models.attention import KVCacheSpec
 from repro.models.parallel import init_params, param_specs
@@ -30,6 +31,7 @@ def serve(argv=None):
     ap.add_argument("--cache-len", type=int, default=128)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = registry.get(args.arch, smoke=args.smoke)
     mesh = jax.make_mesh((1, 1), ("data", "model"))

@@ -3,10 +3,14 @@
     PYTHONPATH=src python -m repro.launch.train --arch minitron-8b --smoke \
         --steps 50 --batch 8 --seq 128 [--grad-gz redoub] [--eb 1e-4]
 
-On this CPU container it trains the reduced (smoke) configs for real —
-a few hundred steps of a ~100M-class model is examples/quickstart.py.
-On a TPU pod the same driver runs the full configs (mesh from
-make_production_mesh).
+On a CPU host it trains the reduced (smoke) configs — a few hundred
+steps of a ~100M-class model is examples/quickstart.py.  On TPU chips the
+same driver runs the full configs over a (data, model) mesh of the local
+devices; ``chip_smoke.py`` runs mamba2-780m at full width this way.
+
+Parameters and optimizer state are created directly in their shardings,
+and the step is compiled ahead of time so its device memory
+(``memory_analysis``) is printed before the first step runs.
 """
 from __future__ import annotations
 
@@ -21,6 +25,7 @@ from repro.checkpoint import checkpoint
 from repro.configs import registry
 from repro.core.collectives import GZConfig
 from repro.data.pipeline import SyntheticStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.shapes import InputShape, train_specs
 from repro.launch.training import make_setup, make_train_step
 from repro.models.parallel import init_params
@@ -48,6 +53,7 @@ def train(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = registry.get(args.arch, smoke=args.smoke)
     n_dev = len(jax.devices())
@@ -66,16 +72,38 @@ def train(argv=None):
     _, bspecs = train_specs(cfg, shape, mesh)
     step_fn = make_train_step(setup, bspecs)
 
-    params = init_params(setup.defs, jax.random.key(args.seed))
-    opt_state = adamw_init(params)
+    init_fn = jax.jit(lambda key: init_params(setup.defs, key),
+                      out_shardings=setup.named(setup.specs))
+    opt_init_fn = jax.jit(adamw_init,
+                          out_shardings=setup.named(setup.opt_specs()))
+    key = jax.random.key(args.seed)
     stream = SyntheticStream(cfg, args.batch, args.seq, seed=args.seed)
 
     print(f"arch={cfg.arch_id} params={cfg.param_count()/1e6:.1f}M "
+          f"layers={cfg.n_layers} d_model={cfg.d_model} vocab={cfg.vocab} "
           f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} "
           f"grad_gz={args.grad_gz}")
+    # Compile from shapes first: the step's device memory is known before
+    # any parameter is allocated.
+    pshapes = jax.eval_shape(init_fn, key)
+    batch = next(stream)
+    t0 = time.time()
+    step_fn = step_fn.lower(
+        pshapes, jax.eval_shape(opt_init_fn, pshapes), batch).compile()
+    mem = step_fn.memory_analysis()
+    gib = lambda b: f"{b / 2**30:.2f}GiB"
+    print(f"train step compiled in {time.time() - t0:.1f}s; per-device "
+          f"memory: args {gib(mem.argument_size_in_bytes)} "
+          f"temp {gib(mem.temp_size_in_bytes)} "
+          f"out {gib(mem.output_size_in_bytes)} "
+          f"(aliased {gib(mem.alias_size_in_bytes)})")
+    params = init_fn(key)
+    opt_state = opt_init_fn(params)
     losses = []
     t0 = time.time()
-    for step, batch in zip(range(args.steps), stream):
+    for step in range(args.steps):
+        if step:
+            batch = next(stream)
         params, opt_state, m = step_fn(params, opt_state, batch)
         loss = float(m["loss"])
         losses.append(loss)

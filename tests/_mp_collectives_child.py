@@ -183,7 +183,7 @@ for algo, pc in (("ring", 2), ("ring", 1), ("redoub", 1)):
                         pipeline_chunks=pc)
     f = shmap(
         lambda x, c=cfg_tiny: gz_allreduce(x[0], "x", c, return_info=True)[1][None],
-        (P("x", None),), P("x", None),
+        (P("x", None),), P("x"),
     )
     ovf = np.asarray(f(rough))
     assert ovf.all(), f"overflow not propagated: {algo} P={pc}"
@@ -194,7 +194,7 @@ xin_rough = np.zeros((N, N * D), np.float32)
 xin_rough[0] = rng.normal(0, 100.0, N * D).astype(np.float32)
 f = shmap(
     lambda x: gz_scatter(x[0], "x", cfg_tiny, return_info=True)[1][None],
-    (P("x", None),), P("x", None),
+    (P("x", None),), P("x"),
 )
 assert np.asarray(f(xin_rough)).all(), "scatter overflow not propagated"
 print("OK overflow propagated (scatter pipelined)")

@@ -364,7 +364,7 @@ while factor > 1e-3:
 assert failing is not None, "no capacity_factor small enough to overflow"
 print(f"OK capacity_shrink_property (first failing factor={failing:g})")
 
-# --- raise policy LAST: the debug-callback raise propagates, and the
+# --- raise policy LAST: the io_callback raise propagates, and the
 # dead runtime tokens it leaves must not poison the exit path ---
 raised = False
 try:

@@ -65,6 +65,27 @@ def test_quantize_pack_byte_identical_under_overflow():
     assert pk_f.shape == (cap,)  # never silently grows
 
 
+def test_fused_pack_round_trip_at_extreme_widths():
+    """One tile mixing bitwidths 0, 1, 2 and 32: the pack kernel's gather
+    rounds run to the narrowest width (33 rounds at b=1), and the wire
+    window carries rows from empty to full 256 words."""
+    rng = np.random.default_rng(2)
+    x = np.zeros((lorenzo.TILE_ROWS, lorenzo.BLOCK), np.float32)
+    x[1] = rng.normal(0, 1e6, lorenzo.BLOCK)  # 32 bits
+    x[2] = -np.arange(lorenzo.BLOCK) * 2 * EB  # deltas of -1: 1 bit
+    x[3, 7] = 2 * EB  # one spike: 2 bits
+    x[5] = rng.normal(0, 1e6, lorenzo.BLOCK)
+    cap = capacity_words_for(x.size, 1.2, lorenzo.BLOCK)
+    pk, bw, an = ops.quantize_pack(jnp.asarray(x), EB, cap)
+    pk_r, bw_r, an_r = ref.quantize_pack_ref(jnp.asarray(x), jnp.float32(EB), cap)
+    assert sorted(set(np.asarray(bw_r).tolist())) == [0, 1, 2, 32]
+    np.testing.assert_array_equal(np.asarray(pk), np.asarray(pk_r))
+    got = ops.unpack_dequantize(pk, bw, an, EB)
+    want = ref.dequantize_ref(bitpack.unpack(pk_r, bw_r, lorenzo.BLOCK), an_r,
+                              jnp.float32(EB))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 @pytest.mark.parametrize("eb", [1e-2, 1e-4])
 def test_unpack_dequantize_reduce_matches_oracle(eb):
     rng = np.random.default_rng(3)
