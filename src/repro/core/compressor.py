@@ -28,6 +28,14 @@ from repro.core import entropy
 from repro.core.compressed import Compressed, capacity_words_for
 from repro.kernels import ops
 
+# The codec layer's named scopes.  They are op metadata in the compiled HLO
+# (``metadata={op_name=...}``), so a device profile can time each codec
+# operation whatever its kernels are called; an op belongs to the outermost
+# of them on its path (a two-pass hop's compress is hop time).
+COMPRESS = "gz.compress"      # f32 -> wire stream
+HOP = "gz.hop"                # one reduce hop, fused or two-pass
+DECOMPRESS = "gz.decompress"  # wire stream -> f32
+
 
 @dataclasses.dataclass(frozen=True)
 class ErrorBoundedLorenzo:
@@ -48,6 +56,7 @@ class ErrorBoundedLorenzo:
     block: int = ops.BLOCK
     fused: bool = True
 
+    @jax.named_scope(COMPRESS)
     def compress(self, x: jnp.ndarray, eb) -> Compressed:
         n = int(x.size)
         eb = jnp.asarray(eb, jnp.float32)
@@ -69,6 +78,7 @@ class ErrorBoundedLorenzo:
         del n
         return bitpack.packed_words(bitwidth, self.block)
 
+    @jax.named_scope(DECOMPRESS)
     def decompress(self, c: Compressed) -> jnp.ndarray:
         if self.fused:
             x2d = ops.unpack_dequantize(c.packed, c.bitwidth, c.anchor, c.eb)
@@ -77,6 +87,7 @@ class ErrorBoundedLorenzo:
             x2d = ops.dequantize(codes, c.anchor, c.eb)
         return ops.from_blocks(x2d, c.n)
 
+    @jax.named_scope(HOP)
     def decompress_reduce(self, c: Compressed, acc: jnp.ndarray) -> jnp.ndarray:
         """acc + decompress(c) without materializing the decompressed array.
 
@@ -93,6 +104,7 @@ class ErrorBoundedLorenzo:
             out2d = ops.dequantize_reduce(codes, c.anchor, c.eb, acc2d)
         return ops.from_blocks(out2d, c.n)
 
+    @jax.named_scope(HOP)
     def decompress_reduce_compress(
         self, c: Compressed, acc: jnp.ndarray, eb_out=None, *,
         return_updated: bool = False,
@@ -147,6 +159,7 @@ class FixedRate:
     rate_bits: int = 8
     block: int = ops.BLOCK
 
+    @jax.named_scope(COMPRESS)
     def compress(self, x: jnp.ndarray, eb) -> Compressed:
         n = int(x.size)
         eb = jnp.asarray(eb, jnp.float32)
@@ -166,14 +179,17 @@ class FixedRate:
         del n
         return bitpack.packed_words(bitwidth, self.block)
 
+    @jax.named_scope(DECOMPRESS)
     def decompress(self, c: Compressed) -> jnp.ndarray:
         codes = bitpack.unpack(c.packed, c.bitwidth, c.block)
         x2d = ops.dequantize(codes, c.anchor, c.eb)
         return ops.from_blocks(x2d, c.n)
 
+    @jax.named_scope(HOP)
     def decompress_reduce(self, c: Compressed, acc: jnp.ndarray) -> jnp.ndarray:
         return acc + self.decompress(c)
 
+    @jax.named_scope(HOP)
     def decompress_reduce_compress(
         self, c: Compressed, acc: jnp.ndarray, eb_out=None, *,
         return_updated: bool = False,
@@ -224,6 +240,7 @@ class EntropyLorenzo:
     fused: bool = True
     lossless: bool = False
 
+    @jax.named_scope(COMPRESS)
     def compress(self, x: jnp.ndarray, eb) -> Compressed:
         n = int(x.size)
         eb = jnp.asarray(eb, jnp.float32)
@@ -249,6 +266,7 @@ class EntropyLorenzo:
         del n
         return entropy.packed_words(bitwidth)
 
+    @jax.named_scope(DECOMPRESS)
     def decompress(self, c: Compressed) -> jnp.ndarray:
         if self.fused:
             x2d = ops.entropy_unpack_dequantize(
@@ -261,6 +279,7 @@ class EntropyLorenzo:
             )
         return ops.from_blocks(x2d, c.n)
 
+    @jax.named_scope(HOP)
     def decompress_reduce(self, c: Compressed, acc: jnp.ndarray) -> jnp.ndarray:
         acc2d = ops.to_blocks(acc)
         if self.fused:
@@ -275,6 +294,7 @@ class EntropyLorenzo:
             )
         return ops.from_blocks(out2d, c.n)
 
+    @jax.named_scope(HOP)
     def decompress_reduce_compress(
         self, c: Compressed, acc: jnp.ndarray, eb_out=None, *,
         return_updated: bool = False,
@@ -300,6 +320,7 @@ class Passthrough:
 
     block: int = ops.BLOCK
 
+    @jax.named_scope(COMPRESS)
     def compress(self, x: jnp.ndarray, eb) -> Compressed:
         n = int(x.size)
         eb = jnp.asarray(eb, jnp.float32)
@@ -319,14 +340,17 @@ class Passthrough:
         del bitwidth
         return jnp.int32(n)
 
+    @jax.named_scope(DECOMPRESS)
     def decompress(self, c: Compressed) -> jnp.ndarray:
         return jax.lax.bitcast_convert_type(
             c.packed[: c.n].astype(jnp.int32), jnp.float32
         )
 
+    @jax.named_scope(HOP)
     def decompress_reduce(self, c: Compressed, acc: jnp.ndarray) -> jnp.ndarray:
         return acc + self.decompress(c)
 
+    @jax.named_scope(HOP)
     def decompress_reduce_compress(
         self, c: Compressed, acc: jnp.ndarray, eb_out=None, *,
         return_updated: bool = False,

@@ -26,8 +26,7 @@ where XLA's scheduler sees the collective become ready — as soon as the
 bucket's cotangents exist, while the rest of backward is still running —
 so comm overlaps compute.  Health flags ride the cotangent of a chained
 scalar token (the only dataflow out of a custom_vjp backward is a
-cotangent), and ``metrics["overlap_modeled"]`` reports the cost model's
-``BucketPlan.overlap_efficiency`` for the configured bucket size.
+cotangent).
 """
 from __future__ import annotations
 
@@ -52,6 +51,12 @@ from repro.core.shmap import shard_map
 from repro.optim.adamw import AdamWConfig, adamw_init, adamw_update
 
 __all__ = ["TrainSetup", "make_setup", "make_train_step", "make_serve_step"]
+
+# Named scopes of the train step's own layers (op metadata in the compiled
+# HLO, read per layer from a device profile); the model's are in
+# models/model.py.
+GRAD_SYNC_SCOPE = "train.grad_sync"  # gradient reduction over the dp axes
+OPTIMIZER_SCOPE = "train.optimizer"  # global norm, AdamW, skip merge
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,12 +85,8 @@ class TrainSetup:
     overlap_sync: bool = False
     # ...packing whole leaves last-layer-first into buckets of about this
     # many f32 bytes (0 never reaches here: make_setup resolves auto to
-    # the BucketPlan's choice)...
+    # the BucketPlan's choice).
     bucket_bytes: int = 16 * 1024 * 1024
-    # ...with the modeled schedule (cost_model.BucketPlan) for
-    # metrics["overlap_modeled"]; None when grad sync is plain psum or
-    # single-rank.
-    overlap_plan: Optional[cost_model.BucketPlan] = None
 
     def opt_specs(self):
         return {
@@ -206,7 +207,6 @@ def make_setup(
         specs=param_specs(defs), opt=opt, grad_gz=grad_gz,
         grad_comms=grad_comms, skip_on_overflow=skip_on_overflow,
         overlap_sync=overlap_sync, bucket_bytes=bucket_bytes,
-        overlap_plan=overlap_plan,
     )
 
 
@@ -250,8 +250,9 @@ def _sync_grads(grads, specs, mesh_axes, grad_comms: dict):
                 g = lax.psum(g, ax)
         return g
 
-    out = jax.tree.map(sync, grads, specs)
-    return out, flag[0]
+    with jax.named_scope(GRAD_SYNC_SCOPE):
+        out = jax.tree.map(sync, grads, specs)
+        return out, flag[0]
 
 
 # ---------------------------------------------------------------------------
@@ -289,27 +290,28 @@ def _bucket_hook_fwd(meta, leaves, token):
 
 def _bucket_hook_bwd(meta, _res, ct):
     gs, g_token = ct
-    flat = [g.astype(jnp.float32).reshape(-1) for g in gs]
-    vec = flat[0] if len(flat) == 1 else jnp.concatenate(flat)
-    # Per-leaf nonfinite probe (the _sync_grads satellite, hook edition):
-    # catches NaN-marked fsdp reduce-scatter cotangents even when this
-    # bucket needs no collective of its own.
-    flag = jnp.any(~jnp.isfinite(vec))
-    for ax, comm in meta.ops:
-        if comm is None:
-            vec = lax.psum(vec, ax)
-        else:
-            res = comm.allreduce(vec)
-            vec = res.value
-            flag = flag | res.overflow | res.nonfinite
-    outs, off = [], 0
-    for shape, dt in zip(meta.shapes, meta.dtypes):
-        size = 1
-        for d in shape:
-            size *= int(d)
-        outs.append(vec[off:off + size].reshape(shape).astype(dt))
-        off += size
-    return tuple(outs), g_token + flag.astype(g_token.dtype)
+    with jax.named_scope(GRAD_SYNC_SCOPE):
+        flat = [g.astype(jnp.float32).reshape(-1) for g in gs]
+        vec = flat[0] if len(flat) == 1 else jnp.concatenate(flat)
+        # Per-leaf nonfinite probe (the _sync_grads satellite, hook
+        # edition): catches NaN-marked fsdp reduce-scatter cotangents even
+        # when this bucket needs no collective of its own.
+        flag = jnp.any(~jnp.isfinite(vec))
+        for ax, comm in meta.ops:
+            if comm is None:
+                vec = lax.psum(vec, ax)
+            else:
+                res = comm.allreduce(vec)
+                vec = res.value
+                flag = flag | res.overflow | res.nonfinite
+        outs, off = [], 0
+        for shape, dt in zip(meta.shapes, meta.dtypes):
+            size = 1
+            for d in shape:
+                size *= int(d)
+            outs.append(vec[off:off + size].reshape(shape).astype(dt))
+            off += size
+        return tuple(outs), g_token + flag.astype(g_token.dtype)
 
 
 _bucket_hook.defvjp(_bucket_hook_fwd, _bucket_hook_bwd)
@@ -398,10 +400,6 @@ def make_train_step(setup: TrainSetup, batch_specs):
     scale = 1.0 / (ctx.tp_size * n_dp)
     specs = setup.specs
     grad_comms = dict(setup.grad_comms)
-    overlap_modeled = float(
-        setup.overlap_plan.overlap_efficiency
-        if (setup.overlap_sync and setup.overlap_plan is not None) else 0.0
-    )
 
     def body(params, opt_state, batch):
         if setup.overlap_sync:
@@ -435,25 +433,24 @@ def make_train_step(setup: TrainSetup, batch_specs):
         # Each health bit is replicated over its OWN dp axis only; make
         # the skip predicate globally consistent before it gates state.
         degraded = lax.psum(degraded.astype(jnp.int32), mesh_axes) > 0
-        gnorm = _global_grad_norm(grads, specs, sizes)
-        new_params, new_opt, om = adamw_update(
-            params, grads, opt_state, setup.opt, grad_norm=gnorm
-        )
-        skipped = jnp.zeros((), jnp.bool_)
-        if setup.skip_on_overflow:
-            new_params = _skip_merge(degraded, new_params, params)
-            new_opt = _skip_merge(degraded, new_opt, opt_state)
-            skipped = degraded
+        with jax.named_scope(OPTIMIZER_SCOPE):
+            gnorm = _global_grad_norm(grads, specs, sizes)
+            new_params, new_opt, om = adamw_update(
+                params, grads, opt_state, setup.opt, grad_norm=gnorm
+            )
+            skipped = jnp.zeros((), jnp.bool_)
+            if setup.skip_on_overflow:
+                new_params = _skip_merge(degraded, new_params, params)
+                new_opt = _skip_merge(degraded, new_opt, opt_state)
+                skipped = degraded
         metrics = {
             "loss": loss, "gnorm": om["gnorm"], "lr": om["lr"],
             "skipped": skipped,
-            "overlap_modeled": jnp.full((), overlap_modeled, jnp.float32),
         }
         return new_params, new_opt, metrics
 
     ospecs = setup.opt_specs()
-    mspecs = {"loss": P(), "gnorm": P(), "lr": P(), "skipped": P(),
-              "overlap_modeled": P()}
+    mspecs = {"loss": P(), "gnorm": P(), "lr": P(), "skipped": P()}
     step = shard_map(
         body,
         mesh=setup.mesh,

@@ -41,6 +41,12 @@ from repro.models.parallel import ParallelCtx, ParamDef
 
 MOE_AUX_COEF = 0.01
 
+# Named scopes of the train step's model layers (op metadata in the compiled
+# HLO, read per layer from a device profile).  Backward ops of a scope sit
+# under ``transpose(jvp(<scope>))``.
+BLOCKS_SCOPE = "model.blocks"  # the layer stack
+HEAD_SCOPE = "model.head"      # final norm, LM head, cross-entropy
+
 
 def _stack(defs, L: int):
     """Add a leading stacked-layer dim to every ParamDef in a tree."""
@@ -143,7 +149,8 @@ class Model:
 
         if ctx.remat != "none":
             f = jax.checkpoint(f)
-        h, auxs = lax.scan(f, h, stacked, unroll=ctx.scan_unroll)
+        with jax.named_scope(BLOCKS_SCOPE):
+            h, auxs = lax.scan(f, h, stacked, unroll=ctx.scan_unroll)
         return (h, jnp.sum(auxs)) if with_aux else (h, None)
 
     def _backbone(self, h, params, *, positions, window=0, cross_kv=None):
@@ -244,21 +251,22 @@ class Model:
         )
         if cfg.n_prefix and cfg.family in ("vlm", "audio"):
             h = h[:, cfg.n_prefix :]
-        h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-        labels = batch["labels"]
-        mask = (labels >= 0).astype(jnp.float32)
-        if cfg.loss_chunk:
-            loss = chunked_vocab_xent(
-                h, params["unembed"], jnp.maximum(labels, 0), mask, ctx,
-                chunk=cfg.loss_chunk,
-            )
-        else:
-            logits = vocab_parallel_logits(h, params["unembed"], ctx)
-            loss = vocab_parallel_xent(logits, jnp.maximum(labels, 0), ctx,
-                                       mask=mask)
-        if cfg.family == "moe":
-            loss = loss + MOE_AUX_COEF * aux / cfg.n_layers
-        return loss
+        with jax.named_scope(HEAD_SCOPE):
+            h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+            labels = batch["labels"]
+            mask = (labels >= 0).astype(jnp.float32)
+            if cfg.loss_chunk:
+                loss = chunked_vocab_xent(
+                    h, params["unembed"], jnp.maximum(labels, 0), mask, ctx,
+                    chunk=cfg.loss_chunk,
+                )
+            else:
+                logits = vocab_parallel_logits(h, params["unembed"], ctx)
+                loss = vocab_parallel_xent(logits, jnp.maximum(labels, 0), ctx,
+                                           mask=mask)
+            if cfg.family == "moe":
+                loss = loss + MOE_AUX_COEF * aux / cfg.n_layers
+            return loss
 
     # ---------------- costing hooks (see launch/costing.py) ----------------
 

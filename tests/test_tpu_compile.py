@@ -24,8 +24,11 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.collectives import GZConfig
 from repro.core.compressed import capacity_words_for
-from repro.core.compressor import lossless_capacity_words
+from repro.core.compressor import (COMPRESS, DECOMPRESS, HOP,
+                                   lossless_capacity_words)
 from repro.kernels import ops
+
+import _scopes
 
 N = 4 * 1024 * 1024
 NB = N // ops.BLOCK
@@ -131,3 +134,35 @@ def test_four_chip_allreduce_compiles_for_v5e(topo, no_compile_cache,
     text = step.lower(x).compile().as_text()
     assert "tpu_custom_call" in text
     assert "collective-permute" in text
+
+
+CODEC_SCOPES = (COMPRESS, HOP, DECOMPRESS)
+
+
+def test_ring_allreduce_kernels_sit_in_codec_scopes(topo, no_compile_cache,
+                                                   monkeypatch):
+    """The program names its codec layer: in the compiled 4-chip ring
+    allreduce every Pallas kernel has an outermost codec scope, and
+    compress, hop and decompress all run."""
+    from repro.core.comm import GZCommunicator
+    from repro.core.shmap import shard_map
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices), ("x",))
+    comm = GZCommunicator("x", config=GZConfig(
+        eb=EB, algo="ring", fused_hop=True, capacity_factor=0.6), axis_size=4)
+    body = lambda x: comm.allreduce(x[0]).value[None]
+    step = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("x", None),),
+                             out_specs=P("x", None)))
+    x = jax.ShapeDtypeStruct((4, N), jnp.float32,
+                             sharding=NamedSharding(mesh, P("x", None)))
+    instrs = _scopes.op_names(step.lower(x).compile().as_text())
+    kernels = {name: op_name for name, (op_name, rhs) in instrs.items()
+               if 'custom_call_target="tpu_custom_call"' in rhs}
+    assert kernels
+    outermost = {}
+    for name, op_name in kernels.items():
+        scopes = [s for s, _ in _scopes.path(op_name) if s in CODEC_SCOPES]
+        assert scopes, (name, op_name)
+        outermost[name] = scopes[0]
+    assert set(outermost.values()) == set(CODEC_SCOPES), outermost
