@@ -445,68 +445,74 @@ def _wire_accounting(op, algo, n_elems, n, capacity_factor, chunks,
     return cap, max(send), max(send_raw)
 
 
+def _stream_elems(op, algo, n_elems, n, chunks):
+    """Elements of one wire stream as the execute layer pads it: the
+    payload a codec kernel sees (pipelined rings and chunked ops pad to
+    whole-tile pieces, intring pads chunks to whole code rows)."""
+    p = max(chunks, 1)
+    chunk = -(-n_elems // max(n, 1))
+    if op == "allreduce" and algo == "redoub" or op == "broadcast":
+        return n_elems
+    if op == "allreduce" and algo == "intring":
+        return ops.n_blocks_for(chunk) * ops.BLOCK
+    if op == "allreduce":  # float ring
+        return _ring_piece_sizes(n_elems, n, chunks)[1]
+    if op in ("reduce_scatter", "allgather"):
+        own = chunk if op == "reduce_scatter" else n_elems
+        if p > 1:
+            quantum = p * _PIECE_QUANTUM
+            return (-(-own // quantum) * quantum) // p
+        return own
+    if op in ("scatter", "all_to_all"):
+        return chunk
+    raise ValueError(f"unknown op {op!r}")
+
+
 def _entry_pricers(op, algo, n_elems, n, capacity_factor, chunks, codec):
     """Per-table-entry pricing closures for one op's transport.
 
     Returns ``(capacity_words, entry_wire(h), entry_raw(h))``: the
     provisioned capacity of one wire stream, and the compressed /
     uncompressed-equivalent bytes one :class:`schedule.Hop` ships —
-    including the execute layer's padding (pipelined rings pad to
-    whole-tile pieces, intring pads chunks to whole code rows).
+    including the execute layer's padding (``_stream_elems``).
     """
     p = max(chunks, 1)
-    if op == "allreduce" and algo == "redoub" or op == "broadcast":
-        cap = codecs.codec_capacity_words(codec, n_elems, capacity_factor)
-        stream = _stream_bytes(n_elems, capacity_factor, codec)
-        return cap, (lambda h: stream), (lambda h: n_elems * 4)
+    piece = _stream_elems(op, algo, n_elems, n, chunks)
+    chunk_in = -(-n_elems // max(n, 1))
     if op == "allreduce" and algo == "intring":
-        # execute pads each chunk to whole row-tiles of int codes
-        chunk = ops.n_blocks_for(-(-n_elems // max(n, 1))) * ops.BLOCK
-        cap = capacity_words_for(chunk, capacity_factor, ops.BLOCK)
-        stream = _int_stream_bytes(chunk, capacity_factor)
-        chunk_in = -(-n_elems // max(n, 1))
+        cap = capacity_words_for(piece, capacity_factor, ops.BLOCK)
+        stream = _int_stream_bytes(piece, capacity_factor)
         return cap, (lambda h: stream), (lambda h: chunk_in * 4)
-    if op == "allreduce":  # float ring
-        chunk, piece = _ring_piece_sizes(n_elems, n, chunks)
-        cap = codecs.codec_capacity_words(codec, piece, capacity_factor)
-        stream = p * _stream_bytes(piece, capacity_factor, codec)
-        chunk_in = -(-n_elems // max(n, 1))
-        return cap, (lambda h: stream), (lambda h: chunk_in * 4)
-    if op == "reduce_scatter":
-        chunk_in = -(-n_elems // max(n, 1))
-        if p > 1:  # execute pads each chunk to p whole-tile pieces
-            quantum = p * _PIECE_QUANTUM
-            piece = (-(-chunk_in // quantum) * quantum) // p
-        else:
-            piece = chunk_in
-        cap = codecs.codec_capacity_words(codec, piece, capacity_factor)
-        stream = p * _stream_bytes(piece, capacity_factor, codec)
-        return cap, (lambda h: stream), (lambda h: chunk_in * 4)
-    if op == "allgather":
-        if p > 1:  # execute pads the own chunk to p whole-tile pieces
-            quantum = p * _PIECE_QUANTUM
-            piece = (-(-n_elems // quantum) * quantum) // p
-        else:
-            piece = n_elems
-        cap = codecs.codec_capacity_words(codec, piece, capacity_factor)
-        stream = p * _stream_bytes(piece, capacity_factor, codec)
+    cap = codecs.codec_capacity_words(codec, piece, capacity_factor)
+    stream = _stream_bytes(piece, capacity_factor, codec)
+    if op == "allreduce" and algo == "redoub" or op == "broadcast":
         return cap, (lambda h: stream), (lambda h: n_elems * 4)
+    if op in ("allreduce", "reduce_scatter"):
+        return cap, (lambda h: p * stream), (lambda h: chunk_in * 4)
+    if op == "allgather":
+        return cap, (lambda h: p * stream), (lambda h: n_elems * 4)
     if op == "scatter":
         # Trimmed-slab schedule: each entry ships one compressed stream
         # per REAL chunk in its slab, so the root's entries sum to
         # exactly n-1 chunk streams at ANY axis size (the padded virtual
         # tree's zero-padding chunks never appear in the table).
-        chunk = -(-n_elems // max(n, 1))
-        cap = codecs.codec_capacity_words(codec, chunk, capacity_factor)
-        stream = _stream_bytes(chunk, capacity_factor, codec)
         return cap, (lambda h: h.chunk_slab[1] * stream), \
-            (lambda h: h.chunk_slab[1] * chunk * 4)
-    if op == "all_to_all":
-        chunk = -(-n_elems // max(n, 1))
-        cap = codecs.codec_capacity_words(codec, chunk, capacity_factor)
-        stream = _stream_bytes(chunk, capacity_factor, codec)
-        return cap, (lambda h: stream), (lambda h: chunk * 4)
-    raise ValueError(f"unknown op {op!r}")
+            (lambda h: h.chunk_slab[1] * piece * 4)
+    return cap, (lambda h: stream), (lambda h: piece * 4)  # all_to_all
+
+
+def _walk_note(op, algo, n_elems, n, chunks, codec, fused):
+    """The ``Plan.notes`` entry naming the wire-stream walk the plan's
+    codec kernels take (``lorenzo.rows_per_step`` of one stream's padded
+    blocks); none where no stream kernel runs: a 1-rank axis, the
+    unfused oracle path, intring's integer codes, or a codec provisioned
+    structurally (passthrough)."""
+    if (n < 2 or not fused or algo == "intring"
+            or codecs.get_codec(codec).capacity_words is not None):
+        return ()
+    nb = ops.n_blocks_for(_stream_elems(op, algo, n_elems, n, chunks))
+    return (f"codec walk: {ops.rows_per_step(nb)} block rows per grid step "
+            f"({nb} blocks a stream)",)
 
 
 def assert_step_count_consistency(n_range=range(2, 34), n_elems: int = 4096,
@@ -1078,7 +1084,9 @@ def _resolve_plan(
                     if algo == "binomial" else ()),
         on_overflow=on_overflow, verify_streams=verify_streams,
         fallback=_fallback_plan(op, n_elems, axis_size, hw),
-        codec=codec, codec_ratio=codec_ratio, notes=notes,
+        codec=codec, codec_ratio=codec_ratio,
+        notes=notes + _walk_note(op, algo, n_elems, axis_size, chunks, codec,
+                                 fused),
         route_table=(schedule.build(op, algo, axis_size)
                      if axis_size >= 2 else None),
     )

@@ -22,27 +22,33 @@ Kernels, each tiled ``(TILE_ROWS, BLOCK)`` over a grid of block-rows:
 Fused-pack layout invariant: BLOCK is a multiple of 32, so every block's
 ``BLOCK * bw_i`` bit payload is a whole number of uint32 words — block
 boundaries are always word-aligned.  That is what makes single-pass
-packing possible on a block-parallel grid: a tile of TILE_ROWS blocks
-emits exactly ``8 * sum(bw)`` words at a word offset carried across the
+packing possible on a block-parallel grid: a grid step's blocks emit
+exactly ``8 * sum(bw)`` words at a word offset carried across the
 sequential TPU grid in SMEM scratch (no global cumsum, no second pass).
 The byte stream is IDENTICAL to ``bitpack.pack(quantize(x))`` — oracle-
 tested in tests/test_fused_pipeline.py.
 
 Wire stream in HBM (DESIGN.md §3.2).  The packed stream stays in HBM
 (``memory_space=pl.ANY``), viewed as ``(lines, LANES)`` uint32 so that
-every DMA moves whole 128-word lines.  A tile's segment starts at an
-arbitrary word offset ``start``: the pack side assembles it in a
-``(WIN_LINES, LANES)`` VMEM window at lane offset ``start % LANES``, ORs
-in the carried partial line of the previous tile, and DMAs the window to
-line ``start // LANES``; the unpack side DMAs the same window in.  Inside
-the window each block row is moved to or from its word offset by a flat
-roll (a dynamic sublane roll plus a dynamic lane roll), and the per-word
-/ per-element bit shuffles are lane gathers within one 128-lane vreg.
-No scatter, no resident capacity-sized block.
+every DMA moves whole 128-word lines.  The stream kernels walk it once
+per grid step of ``rows_per_step(n_blocks)`` block rows (up to 128; the
+unpacked kernels keep TILE_ROWS).  A step's segment starts at an
+arbitrary word offset ``start``: the step's row offsets are one
+exclusive prefix sum of its words column, moved to SMEM once; the pack
+side rotates each row by its lane offset and ORs it, a tile of rows at a
+time, into the (up to) 3 lines it covers of a ``(win_lines(rows),
+LANES)`` VMEM window (line 0 holds the carried partial line of the
+previous step), and DMAs the window to line ``start // LANES``; the
+unpack side DMAs the same window in and rotates each row's lines back
+to lane 0.  A row's cost does not depend on the
+window's size.  The per-word / per-element bit shuffles are lane
+gathers within one 128-lane vreg.  No scatter, no resident
+capacity-sized block.
 
 TPU tiling notes (DESIGN.md §2): BLOCK=256 keeps each Lorenzo block two
 128-lane vregs wide; TILE_ROWS=8 gives an (8, 256) f32 tile = 8 KiB VMEM in,
-8 KiB out, well under VMEM while a multiple of the (8, 128) f32 native tile.
+8 KiB out, a multiple of the (8, 128) f32 native tile; it stays the padding
+unit, and a stream kernel's step of up to 16 such tiles is 128 KiB.
 The per-block cumsum is a log-step lane-roll prefix sum on the VPU; blocks
 are independent so there is no cross-tile carry — this is what replaces
 cuSZp's per-warp layout on the MXU-less part of the chip.
@@ -63,11 +69,31 @@ from jax.experimental.pallas import tpu as pltpu
 BLOCK = 256
 TILE_ROWS = 8
 LANES = 128
-# A tile emits at most TILE_ROWS * BLOCK = 2048 words; placed at a lane
-# offset of up to LANES - 1 that spans 17 lines.  24 keeps the window a
-# whole number of (8, 128) vregs.
-WIN_LINES = 24
+# Tiles per grid step of the wire-stream kernels, at most.
+MAX_TILES_PER_STEP = 16
+# Lines a tile's packed rows are assembled in: TILE_ROWS rows of up to 2
+# lines behind a lane offset span 17, from a line up to 7 past an aligned
+# one.
+_GROUP_LINES = 24
 _SIGN = 0x80000000
+
+
+def rows_per_step(n_blocks: int) -> int:
+    """Block rows a wire-stream kernel walks per grid step: TILE_ROWS
+    times the largest power of two <= MAX_TILES_PER_STEP that divides
+    ``n_blocks / TILE_ROWS`` (n_blocks is a multiple of TILE_ROWS)."""
+    g = MAX_TILES_PER_STEP
+    while (n_blocks // TILE_ROWS) % g:
+        g //= 2
+    return g * TILE_ROWS
+
+
+def win_lines(rows: int) -> int:
+    """Lines of a step's window: its rows emit at most ``rows * BLOCK``
+    words (2 lines a row at width 32), which behind the carried line's
+    lane offset span ``2 * rows + 1`` lines; rounded to whole (8, 128)
+    vregs."""
+    return -(-(2 * rows + 1) // 8) * 8
 
 
 def _umax(u, axis):
@@ -142,8 +168,8 @@ def _scalar_spec():
     return pl.BlockSpec((1, 1), lambda i: (0, 0))
 
 
-def _row_spec(width):
-    return pl.BlockSpec((TILE_ROWS, width), lambda i: (i, 0))
+def _row_spec(width, rows=TILE_ROWS):
+    return pl.BlockSpec((rows, width), lambda i: (i, 0))
 
 
 _ANY = pl.BlockSpec(memory_space=pl.ANY)
@@ -311,38 +337,70 @@ def _unpack_rows(w, sub_bw):
     return (first | straddle) & _width_mask(b)
 
 
-def _words_of_row(words_col, r):
-    """Scalar words of block row ``r`` (traced) of a (TILE_ROWS, 1) column."""
-    row = jax.lax.broadcasted_iota(jnp.int32, words_col.shape, 0)
-    return jnp.sum(jnp.where(row == r, words_col, 0))
+def _row_offsets(words_col, pos_vmem, pos_smem, sem):
+    """Move a step's row offsets to SMEM: lane ``r`` of ``pos_smem`` row 0
+    is block row ``r``'s word offset in the step (an exclusive prefix sum
+    of the (rows, 1) words column), row 1 the inclusive one.  One prefix
+    sum and one small DMA per step; no per-row vector-to-scalar moves."""
+    shape = (words_col.shape[0], LANES)
+    diag = (jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            == jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+    words = jnp.sum(jnp.where(diag, words_col, 0), axis=0, keepdims=True)
+    incl = _row_cumsum(words)
+    pos_vmem[0:1, :] = incl - words
+    pos_vmem[1:2, :] = incl
+    copy = pltpu.make_async_copy(pos_vmem, pos_smem, sem)
+    copy.start()
+    copy.wait()
 
 
-def _flat_roll(x, s):
-    """Roll a (lines, LANES) window by ``s`` words toward higher flat
-    indices (``s`` a non-negative scalar below the window size)."""
-    a, c = s // LANES, s % LANES
-    z = pltpu.roll(pltpu.roll(x, a, 0), c, 1)
-    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    return jnp.where(lane >= c, z, pltpu.roll(z, 1, 0))
-
-
-def _window_start(start, hbm_ref):
-    """Clamp a tile's word offset into the stream: a tile past the
-    provisioned capacity reads/writes the WIN_LINES dump tail, never a
+def _window_start(start, hbm_ref, lines):
+    """Clamp a step's word offset into the stream: a step past the
+    provisioned capacity reads/writes the window-sized dump tail, never a
     valid word."""
-    cap_lines = hbm_ref.shape[0] - WIN_LINES
+    cap_lines = hbm_ref.shape[0] - lines
     s = jnp.minimum(start, cap_lines * LANES)
     return s // LANES, s % LANES
 
 
-def _emit_tile(words, words_col, hbm_ref, off_ref, carry_ref, seg_ref, sem):
-    """Append one tile's packed rows to the HBM wire stream.
+def _pack_groups(rows_ref, widths):
+    """Pack the step's codes in ``rows_ref`` in place, one TILE_ROWS group
+    at a time, so that each group's gather rounds run only to its own
+    narrowest width; ``widths(s)`` gives the sub-width columns of the
+    group's row slice ``s``."""
+    def group(g, carry):
+        s = pl.ds(pl.multiple_of(g * TILE_ROWS, TILE_ROWS), TILE_ROWS)
+        rows_ref[s, :] = _pack_rows(rows_ref[s, :], widths(s))
+        return carry
+
+    jax.lax.fori_loop(0, rows_ref.shape[0] // TILE_ROWS, group, 0)
+
+
+def _tile_loop(body, carry, unroll):
+    """``carry = body(k, carry)`` for the TILE_ROWS rows of a tile.
+    Unrolled for Mosaic, which then overlaps the rows' independent loads
+    and rolls (a rolled loop ran the pack 1.4x and the unpack 2.5x slower
+    on a v5e); a loop in interpret mode, where each unrolled copy would be
+    compiled again."""
+    if unroll:
+        for k in range(TILE_ROWS):
+            carry = body(k, carry)
+        return carry
+    return jax.lax.fori_loop(0, TILE_ROWS, body, carry)
+
+
+def _emit_step(unroll, codes, widths, words_col, hbm_ref, off_ref, carry_ref,
+               seg_ref, rows_ref, pos_vmem, pos_smem, sem, pos_sem):
+    """Pack one step's block rows and append them to the HBM wire stream.
 
     Rows are placed back to back at the carried word offset inside a
-    window that starts on the offset's line; line 0 is ORed with the
-    previous tile's partial last line (blocks are word-aligned, so OR ==
+    window that starts on the offset's line: each row's two lines are
+    rotated by its lane offset and ORed into the (up to) three lines it
+    covers, a tile of rows at a time.  Line 0 starts as the previous
+    step's partial last line (blocks are word-aligned, so OR ==
     concatenation).  The window DMA waits for the previous one: they
-    overlap on that line, and the window buffer is reused.
+    overlap on that line, the previous window's zero tail covers lines
+    this one writes, and the window buffer is reused.
     """
     i = pl.program_id(0)
 
@@ -351,32 +409,51 @@ def _emit_tile(words, words_col, hbm_ref, off_ref, carry_ref, seg_ref, sem):
         off_ref[0] = 0
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
+    rows_ref[...] = codes
+    _pack_groups(rows_ref, widths)
+    _row_offsets(words_col, pos_vmem, pos_smem, pos_sem)
+    rows, lines = rows_ref.shape[0], seg_ref.shape[0]
     start = off_ref[0]
-    line0, b0 = _window_start(start, hbm_ref)
-    line = jax.lax.broadcasted_iota(jnp.int32, seg_ref.shape, 0)
-    lo, hi = words[:, :LANES], words[:, LANES:]
-
-    def place(r, carry):  # row r's two lines, rolled to its word offset
-        seg, off = carry
-        pick = lambda half: pltpu.roll(half, (TILE_ROWS - r) % TILE_ROWS, 0)[:1]
-        placed = jnp.where(line == 0, pick(lo),
-                           jnp.where(line == 1, pick(hi), jnp.uint32(0)))
-        return (seg | _flat_roll(placed, b0 + off),
-                off + _words_of_row(words_col, r))
-
-    seg, total = jax.lax.fori_loop(
-        0, TILE_ROWS, place,
-        (jnp.where(line == 0, carry_ref[...], jnp.uint32(0)), jnp.int32(0)))
-    end = (b0 + total) // LANES
-    carry_ref[...] = pltpu.roll(seg, (WIN_LINES - end) % WIN_LINES, 0)[:1]
+    total = pos_smem[1, rows - 1]
+    line0, b0 = _window_start(start, hbm_ref, lines)
     copy = pltpu.make_async_copy(
-        seg_ref, hbm_ref.at[pl.ds(line0, WIN_LINES)], sem)
+        seg_ref, hbm_ref.at[pl.ds(line0, lines)], sem)
 
     @pl.when(i > 0)
     def _():
-        copy.wait()  # the previous tile's window (same shape and semaphore)
+        copy.wait()  # the previous step's window (same shape and semaphore)
 
-    seg_ref[...] = seg
+    seg_ref[...] = jnp.zeros_like(seg_ref)
+    seg_ref[0:1, :] = carry_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    near = jax.lax.broadcasted_iota(jnp.int32, (_GROUP_LINES, LANES), 0)
+
+    def group(g, carry):  # one aligned window access per tile of rows
+        r0 = pl.multiple_of(g * TILE_ROWS, TILE_ROWS)
+        base = (b0 + pos_smem[0, r0]) // LANES // TILE_ROWS * TILE_ROWS
+
+        def place(k, acc):
+            p = b0 + pos_smem[0, r0 + k]
+            line, c = p // LANES - base, p % LANES
+            row = rows_ref[pl.ds(r0 + k, 1), :]
+            lo = pltpu.roll(row[:, :LANES], c, 1)
+            hi = pltpu.roll(row[:, LANES:], c, 1)
+            wrapped = lane < c
+            parts = (jnp.where(wrapped, jnp.uint32(0), lo),
+                     jnp.where(wrapped, lo, hi),
+                     jnp.where(wrapped, hi, jnp.uint32(0)))
+            for j, part in enumerate(parts):
+                acc = acc | jnp.where(near == line + j, part, jnp.uint32(0))
+            return acc
+
+        acc = _tile_loop(place, jnp.zeros((_GROUP_LINES, LANES), jnp.uint32),
+                         unroll)
+        at = pl.ds(pl.multiple_of(base, TILE_ROWS), _GROUP_LINES)
+        seg_ref[at, :] = seg_ref[at, :] | acc
+        return carry
+
+    jax.lax.fori_loop(0, rows // TILE_ROWS, group, 0)
+    carry_ref[...] = seg_ref[pl.ds((b0 + total) // LANES, 1), :]
     copy.start()
 
     @pl.when(i == pl.num_programs(0) - 1)
@@ -386,105 +463,138 @@ def _emit_tile(words, words_col, hbm_ref, off_ref, carry_ref, seg_ref, sem):
     off_ref[0] = start + total
 
 
-def _fetch_tile(words_col, hbm_ref, off_ref, win_ref, sem):
-    """Read one tile's block rows from the HBM wire stream, each moved to
-    lane 0 of its row: -> (TILE_ROWS, BLOCK) words for ``_unpack_rows``."""
+def _fetch_step(unroll, words_col, hbm_ref, off_ref, win_ref, rows_ref,
+                pos_vmem, pos_smem, sem, pos_sem):
+    """Read one step's block rows from the HBM wire stream, each moved to
+    lane 0 of its row: -> (rows, BLOCK) words for ``_unpack_rows``.  The
+    window DMA runs while the row offsets are computed."""
     @pl.when(pl.program_id(0) == 0)
     def _():
         off_ref[0] = 0
 
+    rows, lines = rows_ref.shape[0], win_ref.shape[0]
     start = off_ref[0]
-    line0, b0 = _window_start(start, hbm_ref)
+    line0, b0 = _window_start(start, hbm_ref, lines)
     copy = pltpu.make_async_copy(
-        hbm_ref.at[pl.ds(line0, WIN_LINES)], win_ref, sem)
+        hbm_ref.at[pl.ds(line0, lines)], win_ref, sem)
     copy.start()
+    _row_offsets(words_col, pos_vmem, pos_smem, pos_sem)
+    total = pos_smem[1, rows - 1]
     copy.wait()
-    win = win_ref[...]
-    size = WIN_LINES * LANES
-    row = jax.lax.broadcasted_iota(jnp.int32, (TILE_ROWS, LANES), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (TILE_ROWS, LANES), 0)
 
-    def take(r, carry):  # the window rolled back to row r's word offset
-        lo, hi, off = carry
-        y = _flat_roll(win, (size - b0 - off) % size)
-        return (jnp.where(row == r, y[0:1], lo), jnp.where(row == r, y[1:2], hi),
-                off + _words_of_row(words_col, r))
+    def take(r):  # row r's (up to) three lines rotated back to lane 0
+        p = b0 + pos_smem[0, r]
+        line, c = p // LANES, p % LANES
+        l0, l1, l2 = (pltpu.roll(win_ref[pl.ds(line + k, 1), :],
+                                 (LANES - c) % LANES, 1) for k in range(3))
+        first = lane < LANES - c
+        return jnp.where(first, l0, l1), jnp.where(first, l1, l2)
 
-    zeros = jnp.zeros((TILE_ROWS, LANES), jnp.uint32)
-    lo, hi, total = jax.lax.fori_loop(0, TILE_ROWS, take,
-                                      (zeros, zeros, jnp.int32(0)))
+    def group(g, carry):  # Mosaic stores rows only as whole aligned tiles
+        r0 = pl.multiple_of(g * TILE_ROWS, TILE_ROWS)
+
+        def gather(k, tile):
+            row_lo, row_hi = take(r0 + k)
+            return (jnp.where(sub == k, row_lo, tile[0]),
+                    jnp.where(sub == k, row_hi, tile[1]))
+
+        zeros = jnp.zeros((TILE_ROWS, LANES), jnp.uint32)
+        lo, hi = _tile_loop(gather, (zeros, zeros), unroll)
+        rows_ref[pl.ds(r0, TILE_ROWS), :] = jnp.concatenate([lo, hi], axis=1)
+        return carry
+
+    jax.lax.fori_loop(0, rows // TILE_ROWS, group, 0)
     off_ref[0] = start + total
-    return jnp.concatenate([lo, hi], axis=1)
+    return rows_ref[...]
 
 
-def _pack_scratch():
-    """off carry, partial-line carry, window buffer, DMA semaphore."""
+def _pack_scratch(rows):
+    """The trailing arguments of ``_emit_step``: word-offset carry,
+    partial-line carry, window, step rows, row offsets in VMEM and SMEM,
+    and the window's and the offsets' DMA semaphores."""
     return [
         pltpu.SMEM((1,), jnp.int32),
         pltpu.VMEM((1, LANES), jnp.uint32),
-        pltpu.VMEM((WIN_LINES, LANES), jnp.uint32),
+        pltpu.VMEM((win_lines(rows), LANES), jnp.uint32),
+        pltpu.VMEM((rows, BLOCK), jnp.uint32),
+        pltpu.VMEM((2, LANES), jnp.int32),
+        pltpu.SMEM((2, LANES), jnp.int32),
+        pltpu.SemaphoreType.DMA(()),
         pltpu.SemaphoreType.DMA(()),
     ]
 
 
-def _unpack_scratch():
-    """off carry, window buffer, DMA semaphore."""
+def _unpack_scratch(rows):
+    """The trailing arguments of ``_fetch_step``: word-offset carry,
+    window, step rows, row offsets in VMEM and SMEM, and the two DMA
+    semaphores."""
     return [
         pltpu.SMEM((1,), jnp.int32),
-        pltpu.VMEM((WIN_LINES, LANES), jnp.uint32),
+        pltpu.VMEM((win_lines(rows), LANES), jnp.uint32),
+        pltpu.VMEM((rows, BLOCK), jnp.uint32),
+        pltpu.VMEM((2, LANES), jnp.int32),
+        pltpu.SMEM((2, LANES), jnp.int32),
+        pltpu.SemaphoreType.DMA(()),
         pltpu.SemaphoreType.DMA(()),
     ]
 
 
-def stream_lines(capacity_words: int) -> int:
+def stream_lines(capacity_words: int, rows: int) -> int:
     """Lines of the (lines, LANES) HBM view of a stream of this capacity,
-    including the WIN_LINES dump tail."""
-    return -(-capacity_words // LANES) + WIN_LINES
+    including the dump tail of one window of ``rows`` block rows."""
+    return -(-capacity_words // LANES) + win_lines(rows)
 
 
-def to_lines(packed: jnp.ndarray) -> jnp.ndarray:
+def to_lines(packed: jnp.ndarray, rows: int) -> jnp.ndarray:
     """Flat wire stream -> zero-padded (lines, LANES) view for the kernels."""
     n = packed.shape[0]
-    return jnp.pad(packed, (0, stream_lines(n) * LANES - n)).reshape(-1, LANES)
+    return jnp.pad(packed, (0, stream_lines(n, rows) * LANES - n)).reshape(
+        -1, LANES)
 
 
-def _quantize_pack_kernel(x_ref, recip_ref, _zeros, packed_ref, bw_ref,
-                          anchor_ref, *scratch):
-    """quantize + zigzag + bitpack in one pass over the tile."""
+def _quantize_pack_kernel(unroll, x_ref, recip_ref, _zeros, packed_ref,
+                          bw_ref, anchor_ref, *scratch):
+    """quantize + zigzag + bitpack in one pass over the step."""
     zig, bw, anchor = _quantize_tile(x_ref[...], recip_ref[0, 0])
     bw_ref[...] = bw
     anchor_ref[...] = anchor
-    _emit_tile(_pack_rows(zig, [bw]), _block_words([bw]), packed_ref,
-               *scratch)
+    _emit_step(unroll, zig, lambda s: [bw_ref[s, :]], _block_words([bw]),
+               packed_ref, *scratch)
 
 
-def _unpack_dequantize_reduce_kernel(packed_ref, bw_ref, anchor_ref, twoeb_ref,
-                                     acc_ref, out_ref, *scratch):
+def _unpack_dequantize_reduce_kernel(unroll, packed_ref, bw_ref, anchor_ref,
+                                     twoeb_ref, acc_ref, out_ref, *scratch):
     """Inverse fusion: packed words + acc -> acc + dequantize(unpack(words)).
 
-    Same SMEM word-offset carry as the pack kernel; the tile DMAs its
+    Same SMEM word-offset carry as the pack kernel; the step DMAs its
     word window from the HBM stream, so the uint32 codes array never
     materializes in HBM on the receive side either.
     """
     bw = bw_ref[...]
-    w = _fetch_tile(_block_words([bw]), packed_ref, *scratch)
+    w = _fetch_step(unroll, _block_words([bw]), packed_ref, *scratch)
     out_ref[...] = acc_ref[...] + _reconstruct(
         _unpack_rows(w, [bw]), anchor_ref[...], twoeb_ref[0, 0])
 
 
-def _unpack_dequantize_kernel(packed_ref, bw_ref, anchor_ref, twoeb_ref,
-                              out_ref, *scratch):
+def _unpack_dequantize_kernel(unroll, packed_ref, bw_ref, anchor_ref,
+                              twoeb_ref, out_ref, *scratch):
     """Pure fused decompress (no accumulator): the allgather/scatter receive
     path, which would otherwise pay a zero-accumulator materialization."""
     bw = bw_ref[...]
-    w = _fetch_tile(_block_words([bw]), packed_ref, *scratch)
+    w = _fetch_step(unroll, _block_words([bw]), packed_ref, *scratch)
     out_ref[...] = _reconstruct(_unpack_rows(w, [bw]), anchor_ref[...],
                                 twoeb_ref[0, 0])
 
 
-def _unpack_reduce_repack_kernel(emit_f32, packed_in_ref, bw_in_ref,
+_N_PACK_SCRATCH = len(_pack_scratch(TILE_ROWS))
+
+
+def _unpack_reduce_repack_kernel(emit_f32, unroll, packed_in_ref, bw_in_ref,
                                  anchor_in_ref, twoeb_ref, acc_ref, recip_ref,
                                  _zeros, *refs):
-    """The single-pass ring hop (DESIGN.md §3.1): per tile, fetch the
+    """The single-pass ring hop (DESIGN.md §3.1): per step, fetch the
     received packed segment, unpack + un-zigzag + prefix-sum + dequantize,
     add the local accumulator chunk, then immediately re-quantize, zigzag
     and pack the updated chunk onto the outgoing wire stream.  The f32
@@ -497,7 +607,8 @@ def _unpack_reduce_repack_kernel(emit_f32, packed_in_ref, bw_in_ref,
     outs, scratch = refs[:n_out], refs[n_out:]
     packed_out_ref, bw_out_ref, anchor_out_ref = outs[:3]
     bw_in = bw_in_ref[...]
-    w = _fetch_tile(_block_words([bw_in]), packed_in_ref, *scratch[4:])
+    w = _fetch_step(unroll, _block_words([bw_in]), packed_in_ref,
+                    *scratch[_N_PACK_SCRATCH:])
     x = acc_ref[...] + _reconstruct(_unpack_rows(w, [bw_in]),
                                     anchor_in_ref[...], twoeb_ref[0, 0])
     zig, bw, anchor = _quantize_tile(x, recip_ref[0, 0])
@@ -505,8 +616,8 @@ def _unpack_reduce_repack_kernel(emit_f32, packed_in_ref, bw_in_ref,
     anchor_out_ref[...] = anchor
     if emit_f32:
         outs[3][...] = x
-    _emit_tile(_pack_rows(zig, [bw]), _block_words([bw]), packed_out_ref,
-               *scratch[:4])
+    _emit_step(unroll, zig, lambda s: [bw_out_ref[s, :]], _block_words([bw]),
+               packed_out_ref, *scratch[:_N_PACK_SCRATCH])
 
 
 @functools.partial(
@@ -538,38 +649,41 @@ def unpack_reduce_repack(
     updated f32 (n_blocks, BLOCK)]).
     """
     n_blocks = acc.shape[0]
+    rows = rows_per_step(n_blocks)
     twoeb = (2.0 * eb_in).reshape(1, 1).astype(jnp.float32)
     recip = (1.0 / (2.0 * eb_out)).reshape(1, 1).astype(jnp.float32)
-    out_lines = stream_lines(capacity_words)
-    out_specs = [_ANY, _row_spec(1), _row_spec(1)]
+    out_lines = stream_lines(capacity_words, rows)
+    col = _row_spec(1, rows)
+    out_specs = [_ANY, col, col]
     out_shape = [
         jax.ShapeDtypeStruct((out_lines, LANES), jnp.uint32),
         jax.ShapeDtypeStruct((n_blocks, 1), jnp.int32),
         jax.ShapeDtypeStruct((n_blocks, 1), jnp.int32),
     ]
     if emit_f32:
-        out_specs.append(_row_spec(BLOCK))
+        out_specs.append(_row_spec(BLOCK, rows))
         out_shape.append(jax.ShapeDtypeStruct((n_blocks, BLOCK), jnp.float32))
     res = pl.pallas_call(
-        functools.partial(_unpack_reduce_repack_kernel, emit_f32),
-        grid=(n_blocks // TILE_ROWS,),
+        functools.partial(_unpack_reduce_repack_kernel, emit_f32,
+                          not interpret),
+        grid=(n_blocks // rows,),
         in_specs=[
             _ANY,
-            _row_spec(1),
-            _row_spec(1),
+            col,
+            col,
             _scalar_spec(),
-            _row_spec(BLOCK),
+            _row_spec(BLOCK, rows),
             _scalar_spec(),
             _ANY,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=_pack_scratch() + _unpack_scratch(),
+        scratch_shapes=_pack_scratch(rows) + _unpack_scratch(rows),
         input_output_aliases={6: 0},
         compiler_params=_SEQUENTIAL,
         interpret=interpret,
-    )(to_lines(packed), bitwidth[:, None], anchor[:, None], twoeb, acc, recip,
-      jnp.zeros((out_lines, LANES), jnp.uint32))
+    )(to_lines(packed, rows), bitwidth[:, None], anchor[:, None], twoeb, acc,
+      recip, jnp.zeros((out_lines, LANES), jnp.uint32))
     packed_out = res[0].reshape(-1)[:capacity_words]
     if emit_f32:
         return packed_out, res[1][:, 0], res[2][:, 0], res[3]
@@ -588,19 +702,21 @@ def quantize_pack(
     n_blocks must be a multiple of TILE_ROWS (ops.py pads).
     """
     n_blocks = x2d.shape[0]
+    rows = rows_per_step(n_blocks)
     recip = (1.0 / (2.0 * eb)).reshape(1, 1).astype(jnp.float32)
-    lines = stream_lines(capacity_words)
+    lines = stream_lines(capacity_words, rows)
+    col = _row_spec(1, rows)
     packed, bw, anchor = pl.pallas_call(
-        _quantize_pack_kernel,
-        grid=(n_blocks // TILE_ROWS,),
-        in_specs=[_row_spec(BLOCK), _scalar_spec(), _ANY],
-        out_specs=[_ANY, _row_spec(1), _row_spec(1)],
+        functools.partial(_quantize_pack_kernel, not interpret),
+        grid=(n_blocks // rows,),
+        in_specs=[_row_spec(BLOCK, rows), _scalar_spec(), _ANY],
+        out_specs=[_ANY, col, col],
         out_shape=[
             jax.ShapeDtypeStruct((lines, LANES), jnp.uint32),
             jax.ShapeDtypeStruct((n_blocks, 1), jnp.int32),
             jax.ShapeDtypeStruct((n_blocks, 1), jnp.int32),
         ],
-        scratch_shapes=_pack_scratch(),
+        scratch_shapes=_pack_scratch(rows),
         input_output_aliases={2: 0},
         compiler_params=_SEQUENTIAL,
         interpret=interpret,
@@ -619,17 +735,19 @@ def unpack_dequantize(
 ):
     """Fused unpack + dequantize: packed stream -> f32 (n_blocks, BLOCK)."""
     n_blocks = bitwidth.shape[0]
+    rows = rows_per_step(n_blocks)
     twoeb = (2.0 * eb).reshape(1, 1).astype(jnp.float32)
+    col = _row_spec(1, rows)
     return pl.pallas_call(
-        _unpack_dequantize_kernel,
-        grid=(n_blocks // TILE_ROWS,),
-        in_specs=[_ANY, _row_spec(1), _row_spec(1), _scalar_spec()],
-        out_specs=_row_spec(BLOCK),
+        functools.partial(_unpack_dequantize_kernel, not interpret),
+        grid=(n_blocks // rows,),
+        in_specs=[_ANY, col, col, _scalar_spec()],
+        out_specs=_row_spec(BLOCK, rows),
         out_shape=jax.ShapeDtypeStruct((n_blocks, BLOCK), jnp.float32),
-        scratch_shapes=_unpack_scratch(),
+        scratch_shapes=_unpack_scratch(rows),
         compiler_params=_SEQUENTIAL,
         interpret=interpret,
-    )(to_lines(packed), bitwidth[:, None], anchor[:, None], twoeb)
+    )(to_lines(packed, rows), bitwidth[:, None], anchor[:, None], twoeb)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -647,18 +765,19 @@ def unpack_dequantize_reduce(
     ``packed``: uint32[capacity_words]; ``acc``: f32 (n_blocks, BLOCK).
     """
     n_blocks = acc.shape[0]
+    rows = rows_per_step(n_blocks)
     twoeb = (2.0 * eb).reshape(1, 1).astype(jnp.float32)
+    col = _row_spec(1, rows)
     return pl.pallas_call(
-        _unpack_dequantize_reduce_kernel,
-        grid=(n_blocks // TILE_ROWS,),
-        in_specs=[_ANY, _row_spec(1), _row_spec(1), _scalar_spec(),
-                  _row_spec(BLOCK)],
-        out_specs=_row_spec(BLOCK),
+        functools.partial(_unpack_dequantize_reduce_kernel, not interpret),
+        grid=(n_blocks // rows,),
+        in_specs=[_ANY, col, col, _scalar_spec(), _row_spec(BLOCK, rows)],
+        out_specs=_row_spec(BLOCK, rows),
         out_shape=jax.ShapeDtypeStruct((n_blocks, BLOCK), jnp.float32),
-        scratch_shapes=_unpack_scratch(),
+        scratch_shapes=_unpack_scratch(rows),
         compiler_params=_SEQUENTIAL,
         interpret=interpret,
-    )(to_lines(packed), bitwidth[:, None], anchor[:, None], twoeb, acc)
+    )(to_lines(packed, rows), bitwidth[:, None], anchor[:, None], twoeb, acc)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -14,6 +14,7 @@ from repro.kernels import lorenzo
 
 BLOCK = lorenzo.BLOCK
 TILE_ROWS = lorenzo.TILE_ROWS
+rows_per_step = lorenzo.rows_per_step
 
 
 def _interpret() -> bool:

@@ -84,7 +84,8 @@ assert np.array_equal(out_d, out_l), "default != explicit codec='lorenzo'"
 pd = c_default.plan("allreduce", (D,))
 pl = c_lorenzo.plan("allreduce", (D,))
 assert pd is pl, "default and codec='lorenzo' must share one cache entry"
-assert pd.codec == "lorenzo" and pd.notes == ()
+assert pd.codec == "lorenzo" and len(pd.notes) == 1  # the kernels' walk
+assert pd.notes[0].startswith("codec walk: ")
 ok("default-is-lorenzo")
 
 # -- entropy == lorenzo bitwise on both allreduce algorithms ----------------

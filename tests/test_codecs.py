@@ -296,7 +296,9 @@ def test_plan_carries_codec_and_config_roundtrip():
 
 def test_default_plan_unchanged_by_registry():
     p = _comm().plan("allreduce", 8192)
-    assert p.codec == "lorenzo" and p.notes == ()
+    # no codec-resolution note: only the record of the kernels' walk
+    assert p.codec == "lorenzo" and len(p.notes) == 1
+    assert p.notes[0].startswith("codec walk: ")
     assert p.fused_hop is True
     # Wire accounting through the codec path is the pre-registry number.
     cap, wire, raw = comm._wire_accounting(

@@ -47,11 +47,12 @@ def _field(rng, n, kind):
 
 @pytest.mark.parametrize("kind", ["smooth", "boundary", "spiky"])
 @pytest.mark.parametrize("eb_in,eb_out", [(1e-3, 1e-3), (1e-2, 1e-4)])
-def test_fused_hop_kernel_byte_identical_to_composition(kind, eb_in, eb_out):
+@pytest.mark.parametrize("rows", [24, 96, 256])  # 8, 32, 128 rows a step
+def test_fused_hop_kernel_byte_identical_to_composition(kind, eb_in, eb_out,
+                                                        rows):
     # deterministic per-parametrization seed (hash() is salted per process)
     seed = ["smooth", "boundary", "spiky"].index(kind) * 10 + int(eb_in * 1e4)
     rng = np.random.default_rng(seed)
-    rows = 24
     x2 = jnp.asarray(_field(rng, rows * B, kind).reshape(rows, B))
     a2 = jnp.asarray(rng.normal(0, 1, (rows, B)).astype(np.float32))
     cap = capacity_words_for(rows * B, 1.3, B)
@@ -72,21 +73,23 @@ def test_fused_hop_kernel_byte_identical_to_composition(kind, eb_in, eb_out):
     np.testing.assert_array_equal(np.asarray(ga), np.asarray(ca))
 
 
-def test_fused_hop_kernel_byte_identical_under_output_overflow():
+@pytest.mark.parametrize("rows,small", [(32, 64), (256, 64), (256, 5000)])
+def test_fused_hop_kernel_byte_identical_under_output_overflow(rows, small):
     """A starved OUTPUT capacity truncates both paths identically: the
     valid prefix stays byte-identical, the overflow lands in the dump
-    tail, and the stream never silently grows."""
+    tail, and the stream never silently grows (5000 words run out inside
+    the first step of 128 rows, so the second starts on the dump tail)."""
     rng = np.random.default_rng(5)
-    rows = 32
     x2 = jnp.asarray(rng.normal(0, 100.0, (rows, B)).astype(np.float32))
     a2 = jnp.asarray(rng.normal(0, 1, (rows, B)).astype(np.float32))
     cap_in = capacity_words_for(rows * B, 1.3, B)
     pk, bw, an = ops.quantize_pack(x2, 1e-3, cap_in)
-    small = 64
-    fp, fb, _ = ops.unpack_reduce_repack(pk, bw, an, 1e-3, a2, 1e-3, small)
-    ux = ops.unpack_dequantize_reduce(pk, bw, an, 1e-3, a2)
-    cp, _, _ = ops.quantize_pack(ux, 1e-3, small)
-    np.testing.assert_array_equal(np.asarray(fp), np.asarray(cp))
+    for emit in (False, True):
+        fp, fb = ops.unpack_reduce_repack(pk, bw, an, 1e-3, a2, 1e-3, small,
+                                          emit_f32=emit)[:2]
+        ux = ops.unpack_dequantize_reduce(pk, bw, an, 1e-3, a2)
+        cp, _, _ = ops.quantize_pack(ux, 1e-3, small)
+        np.testing.assert_array_equal(np.asarray(fp), np.asarray(cp))
     assert fp.shape == (small,)
     from repro.core import bitpack
 

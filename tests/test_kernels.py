@@ -54,15 +54,20 @@ def test_fused_dequantize_reduce_matches_ref(eb):
 
 
 @pytest.mark.parametrize("eb", EBS)
-@pytest.mark.parametrize("rows", [8, 24])
+@pytest.mark.parametrize("rows", [8, 24, 96, 256])
 def test_error_bound_holds_end_to_end(eb, rows):
-    """The fundamental compressor invariant: |x - x'| <= eb."""
+    """The fundamental compressor invariant: |x - x'| <= eb, and the wire
+    stream kernels (8, 32 and 128 block rows a grid step at these sizes)
+    give the same reconstruction as the unpacked ones."""
     rng = np.random.default_rng(rows)
     x = _field(rng, rows * lorenzo.BLOCK).reshape(rows, lorenzo.BLOCK)
     codes, _, anchor = ops.quantize(jnp.asarray(x), eb)
     x2 = np.asarray(ops.dequantize(codes, anchor, eb))
     # eb plus f32 relative rounding of q*2eb for large |x|
     assert np.abs(x - x2).max() <= eb * (1 + 1e-3) + np.abs(x).max() * 2e-7
+    packed, bw, anchor_p = ops.quantize_pack(jnp.asarray(x), eb, x.size)
+    x3 = np.asarray(ops.unpack_dequantize(packed, bw, anchor_p, eb))
+    np.testing.assert_array_equal(x3, x2)
 
 
 def test_bitwidth_exact_at_powers_of_two():

@@ -111,6 +111,7 @@ KERNELS = _kernel_cases()
 
 @pytest.mark.parametrize("name", list(KERNELS))
 def test_codec_kernel_compiles_for_v5e(chip, name):
+    assert ops.rows_per_step(NB) == 128  # the stream kernels' widest walk
     fn, args = KERNELS[name]
     compiled = jax.jit(fn).lower(*(chip(*a) for a in args)).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
@@ -166,3 +167,28 @@ def test_ring_allreduce_kernels_sit_in_codec_scopes(topo, no_compile_cache,
         assert scopes, (name, op_name)
         outermost[name] = scopes[0]
     assert set(outermost.values()) == set(CODEC_SCOPES), outermost
+
+
+def test_rows_per_step():
+    """TILE_ROWS times the largest power of two <= 16 that divides the
+    tile count: 8 rows for one tile or an odd count, up to 128."""
+    got = {nb: ops.rows_per_step(nb)
+           for nb in (8, 16, 24, 32, 64, 96, 128, 256, 1032, NB)}
+    assert got == {8: 8, 16: 16, 24: 8, 32: 32, 64: 64, 96: 32, 128: 128,
+                   256: 128, 1032: 8, NB: 128}
+
+
+def test_allreduce_cell_plan_notes_its_walk():
+    """The plan of the benchmark's allreduce cell (16,777,216 f32 per rank
+    on a 4-rank ring, each piece of the 16 MiB chunk) records that its
+    stream kernels walk 128 block rows per grid step."""
+    from repro.core.comm import GZCommunicator
+
+    comm = GZCommunicator("x", config=GZConfig(
+        eb=EB, codec="lorenzo", capacity_factor=0.6, fused_hop=True,
+        algo="ring", on_overflow="flag"), axis_size=4)
+    plan = comm.plan("allreduce", 4 * N)
+    nb = ops.n_blocks_for(N // plan.pipeline_chunks)
+    walks = [n for n in plan.notes if n.startswith("codec walk: ")]
+    assert walks == [
+        f"codec walk: 128 block rows per grid step ({nb} blocks a stream)"]
